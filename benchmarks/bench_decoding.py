@@ -12,6 +12,7 @@ import numpy as np
 
 from benchmarks.common import emit
 from repro.api import GenerationConfig, LVLM
+from repro.launch.cache import enable_compile_cache
 
 
 def speculative() -> None:
@@ -62,4 +63,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -14,6 +14,7 @@ import numpy as np
 
 from benchmarks.common import emit, time_jit
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 # analysis: allow L001 (micro-bench: times internal kv-cache kernels
 # directly; the facade would add dispatch overhead to the measurement)
 from repro.core.kv_cache.budget import (adaptive_budgets, cake_layer_scores,
@@ -125,18 +126,18 @@ def paging() -> None:
     b, hq, kvh, d, page, pps = 4, 8, 2, 32, 16, 8
     P = 64
     q = jnp.asarray(rng.randn(b, hq, d), jnp.float32)
-    kp = jnp.asarray(rng.randn(P, page, kvh, d), jnp.float32)
-    vp = jnp.asarray(rng.randn(P, page, kvh, d), jnp.float32)
+    kp = jnp.asarray(rng.randn(kvh, P, page, d), jnp.float32)
+    vp = jnp.asarray(rng.randn(kvh, P, page, d), jnp.float32)
     bt = jnp.asarray(rng.choice(P, (b, pps)), jnp.int32)
     sl = jnp.asarray(rng.randint(page, pps * page, b), jnp.int32)
     us_paged = time_jit(jax.jit(
         lambda *a: ref.paged_attention_ref(*a)), q, kp, vp, bt, sl)
-    k_contig = kp[bt].reshape(b, pps * page, kvh, d)
-    v_contig = vp[bt].reshape(b, pps * page, kvh, d)
+    # [KVH, B, pps, page, D] -> contiguous [B, KVH, pps * page, D]
+    k_contig = jnp.moveaxis(kp[:, bt].reshape(kvh, b, pps * page, d), 0, 1)
+    v_contig = jnp.moveaxis(vp[:, bt].reshape(kvh, b, pps * page, d), 0, 1)
     us_contig = time_jit(jax.jit(
         lambda qq, kk, vv: ref.flash_attention_ref(
-            jnp.swapaxes(qq[:, None], 1, 2).reshape(b, hq, 1, d),
-            jnp.swapaxes(kk, 1, 2), jnp.swapaxes(vv, 1, 2), causal=False)),
+            qq.reshape(b, hq, 1, d), kk, vv, causal=False)),
         q, k_contig, v_contig)
     emit("paging/gather_overhead", us_paged,
          f"contiguous_us={us_contig:.1f}")
@@ -149,4 +150,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

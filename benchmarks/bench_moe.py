@@ -14,6 +14,7 @@ import numpy as np
 
 from benchmarks.common import emit
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import build
 from repro.models.moe import apply_moe
 from repro.training import (OptimizerConfig, SyntheticDataConfig,
@@ -75,4 +76,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

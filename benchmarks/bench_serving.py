@@ -29,6 +29,7 @@ from benchmarks.common import emit
 from repro.api import (AdmissionConfig, CostModel, EngineConfig,
                        GenerationConfig, LVLM, PoolConfig, Request, goodput,
                        simulate_colocated, simulate_disaggregated)
+from repro.launch.cache import enable_compile_cache
 
 
 def _pcts(out, metric: str) -> str:
@@ -568,6 +569,7 @@ def main() -> None:
                          "with --only-disagg-burst); validate with "
                          "python -m repro.obs.validate")
     args = ap.parse_args()
+    enable_compile_cache()
     counts = tuple(int(x) for x in str(args.replicas).split(",") if x)
     presets = tuple(p for p in str(args.compression).split(",") if p)
     if args.emit_bench:

@@ -16,6 +16,7 @@ import numpy as np
 
 from benchmarks.common import emit, time_jit
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 # analysis: allow L001 (micro-bench: times internal pruning kernels
 # directly rather than through the per-request facade strategies)
 from repro.core.token_compression.pruning import PRUNERS
@@ -70,4 +71,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -11,6 +11,7 @@ import time
 
 from benchmarks import (bench_decoding, bench_kernels, bench_kv_cache,
                         bench_moe, bench_serving, bench_token_compression)
+from repro.launch.cache import enable_compile_cache
 
 CATEGORIES = {
     "token_compression": bench_token_compression.run,   # survey dim 1
@@ -24,6 +25,7 @@ CATEGORIES = {
 
 def main() -> None:
     picks = sys.argv[1:] or list(CATEGORIES)
+    enable_compile_cache()
     print("name,us_per_call,derived")
     t0 = time.time()
     for name in picks:

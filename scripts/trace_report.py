@@ -1,6 +1,7 @@
 """Per-stage attribution report over a repro.obs JSONL event log.
 
-    PYTHONPATH=src python -m repro.launch.serve --roles prefill,decode \
+    PYTHONPATH=src python -m repro.launch.serve --smoke \
+        --roles prefill,decode \
         --open-loop 2000 --trace-events /tmp/events.jsonl
     PYTHONPATH=src python scripts/trace_report.py /tmp/events.jsonl
 

@@ -36,9 +36,11 @@ scheduler.py, with the taxonomy dimensions as config switches:
 NOTE: ``repro.api`` (``LVLM`` / ``GenerationConfig``) is the public surface;
 construct ``Engine`` directly only for internal-layer control.
 
-Time is a virtual clock advanced by an analytic per-iteration cost model, so
-TTFT/TPOT/JCT metrics are deterministic and hardware-independent (the
-container has no TPU); FLOPs/bytes fidelity lives in the roofline pass.
+Time is a virtual clock advanced by an analytic per-iteration cost model
+(``CostModel``), so TTFT/TPOT/JCT metrics are deterministic and
+hardware-independent -- they are model outputs, not measurements, on a CPU
+and on a TPU alike. The jitted programs themselves run on whatever backend
+JAX selects (``chip_smoke.py`` drives them on one TPU v5e).
 """
 from __future__ import annotations
 

@@ -83,14 +83,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
                     kv_len: int | None = None, q_offset: int = 0,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: [B, H, Sq, D]; k, v: [B, KVH, Sk, D]. Returns [B, H, Sq, D].
 
     Sq/Sk are padded to block multiples internally; ``kv_len`` marks valid
     keys (defaults to Sk). ``q_offset``: absolute position of q[...,0,:]
     for causal masking (chunked prefill / decode-block use).
-    ``interpret=True`` executes the kernel body on CPU (this container);
-    on a TPU runtime pass interpret=False.
+    ``interpret=True`` executes the kernel body in the Pallas interpreter;
+    ``repro.kernels.ops.interpret_mode()`` chooses it by backend.
     """
     b, h, sq, d = q.shape
     kvh, sk = k.shape[1], k.shape[2]

@@ -1,8 +1,9 @@
 """Public jit'd kernel entry points with shape checks + backend dispatch.
 
-On a TPU runtime the Pallas kernels compile natively (interpret=False); on
-this CPU container they run in interpret mode, and callers that want XLA-
-compiled speed on CPU can force the pure-jnp reference (``impl='ref'``).
+``interpret_mode()`` is the one place the Pallas interpret choice is made:
+on a TPU backend the kernels compile natively (interpret=False); on any
+other backend they run in interpret mode. Callers that want XLA-compiled
+speed off the TPU can force the pure-jnp reference (``impl='ref'``).
 """
 from __future__ import annotations
 
@@ -14,8 +15,9 @@ from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.paged_attention import paged_attention as _paged
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """True unless the default backend is a TPU (Pallas interpret mode)."""
+    return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -34,22 +36,23 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         return _ref.flash_attention_ref(q, k, v, causal=causal,
                                         kv_len=kv_len, window=window)
     return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
-                  block_k=block_k, kv_len=kv_len, interpret=not _on_tpu())
+                  block_k=block_k, kv_len=kv_len, interpret=interpret_mode())
 
 
 def paged_attention(q, k_pages, v_pages, block_table, seq_lens, *,
                     impl: str = "auto"):
-    """Paged decode attention. q [B,H,D] -> [B,H,D]."""
+    """Paged decode attention over head-major pages [KVH,P,page,D].
+    q [B,H,D] -> [B,H,D]."""
     if q.ndim != 3 or k_pages.ndim != 4:
         raise ValueError("paged_attention expects q rank-3, pages rank-4")
     if k_pages.shape != v_pages.shape:
         raise ValueError("k_pages/v_pages shape mismatch")
     if block_table.ndim != 2 or block_table.shape[0] != q.shape[0]:
         raise ValueError("block_table must be [B, pages_per_seq]")
-    if q.shape[1] % k_pages.shape[2]:
+    if q.shape[1] % k_pages.shape[0]:
         raise ValueError("H must be a multiple of KVH")
     if impl == "ref":
         return _ref.paged_attention_ref(q, k_pages, v_pages, block_table,
                                         seq_lens)
     return _paged(q, k_pages, v_pages, block_table, seq_lens,
-                  interpret=not _on_tpu())
+                  interpret=interpret_mode())

@@ -12,6 +12,11 @@ equivalent of the CUDA kernel's shared-memory gather loop.
 Grid: (batch, kv_head, pages_per_seq); the last axis is sequential, carrying
 the online-softmax state (m, l, acc) for the G grouped q-heads in VMEM
 scratch. One q token per request (autoregressive decode step).
+
+Pages are laid out HEAD-MAJOR, ``[KVH, P, page, D]``, so one grid step's
+block is a dense ``(page, D)`` tile: the TPU lowering requires a block's
+last two dims to be tile-aligned (8 x 128) or whole, which a page-major
+``[P, page, KVH, D]`` pool can only meet by loading all KV heads per step.
 """
 from __future__ import annotations
 
@@ -39,8 +44,8 @@ def _paged_kernel(seq_lens_ref, block_table_ref, q_ref, k_ref, v_ref, o_ref,
 
     g, d = q_ref.shape[2], q_ref.shape[3]
     q = q_ref[0, 0].astype(jnp.float32) / (d ** 0.5)       # [G, D]
-    k = k_ref[0, :, 0].astype(jnp.float32)                  # [page, D]
-    v = v_ref[0, :, 0].astype(jnp.float32)                  # [page, D]
+    k = k_ref[0, 0].astype(jnp.float32)                     # [page, D]
+    v = v_ref[0, 0].astype(jnp.float32)                     # [page, D]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [G, page]
@@ -66,13 +71,13 @@ def _paged_kernel(seq_lens_ref, block_table_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q, k_pages, v_pages, block_table, seq_lens, *,
-                    interpret: bool = True) -> jax.Array:
-    """q: [B, H, D]; k_pages/v_pages: [P, page, KVH, D];
+                    interpret: bool) -> jax.Array:
+    """q: [B, H, D]; k_pages/v_pages: [KVH, P, page, D];
     block_table: [B, pages_per_seq] int32; seq_lens: [B] int32.
     Returns [B, H, D].
     """
     b, h, d = q.shape
-    p_total, page, kvh, _ = k_pages.shape
+    kvh, p_total, page, _ = k_pages.shape
     pages_per_seq = block_table.shape[1]
     assert h % kvh == 0
     g = h // kvh
@@ -89,11 +94,11 @@ def paged_attention(q, k_pages, v_pages, block_table, seq_lens, *,
                 pl.BlockSpec((1, 1, g, d),
                              lambda bi, hi, pi, sl, bt: (bi, hi, 0, 0)),
                 # the paged gather: physical page id from the block table
-                pl.BlockSpec((1, page, 1, d),
-                             lambda bi, hi, pi, sl, bt: (bt[bi, pi], 0, hi,
+                pl.BlockSpec((1, 1, page, d),
+                             lambda bi, hi, pi, sl, bt: (hi, bt[bi, pi], 0,
                                                          0)),
-                pl.BlockSpec((1, page, 1, d),
-                             lambda bi, hi, pi, sl, bt: (bt[bi, pi], 0, hi,
+                pl.BlockSpec((1, 1, page, d),
+                             lambda bi, hi, pi, sl, bt: (hi, bt[bi, pi], 0,
                                                          0)),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d),

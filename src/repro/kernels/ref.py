@@ -44,26 +44,26 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, seq_lens
     """Decode attention over a paged KV pool, oracle.
 
     q          : [B, H, D]           one query token per request
-    k_pages    : [P, page, KVH, D]   physical page pool
-    v_pages    : [P, page, KVH, D]
+    k_pages    : [KVH, P, page, D]   physical page pool, head-major
+    v_pages    : [KVH, P, page, D]
     block_table: [B, pages_per_seq]  int32 physical page ids
     seq_lens   : [B]                 int32 valid tokens per request
     Returns [B, H, D].
     """
     b, h, d = q.shape
-    p_total, page, kvh, _ = k_pages.shape
+    kvh, p_total, page, _ = k_pages.shape
     pages_per_seq = block_table.shape[1]
     g = h // kvh
-    # gather the logical KV for each request: [B, pages*page, KVH, D]
-    k_log = k_pages[block_table].reshape(b, pages_per_seq * page, kvh, d)
-    v_log = v_pages[block_table].reshape(b, pages_per_seq * page, kvh, d)
+    # gather the logical KV for each request: [KVH, B, pages*page, D]
+    k_log = k_pages[:, block_table].reshape(kvh, b, pages_per_seq * page, d)
+    v_log = v_pages[:, block_table].reshape(kvh, b, pages_per_seq * page, d)
     qf = q.reshape(b, kvh, g, d).astype(jnp.float32) / (d ** 0.5)
-    s = jnp.einsum("bkgd,bckd->bkgc", qf, k_log.astype(jnp.float32))
+    s = jnp.einsum("bkgd,kbcd->bkgc", qf, k_log.astype(jnp.float32))
     pos = jnp.arange(pages_per_seq * page)
     valid = pos[None] < seq_lens[:, None]                   # [B, C]
     s = jnp.where(valid[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgc,bckd->bkgd", p, v_log.astype(jnp.float32))
+    o = jnp.einsum("bkgc,kbcd->bkgd", p, v_log.astype(jnp.float32))
     return o.reshape(b, h, d).astype(q.dtype)
 
 

@@ -3,6 +3,7 @@ never touch jax device state)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -11,13 +12,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     Axes: "data" (batch / fsdp), "model" (tensor/expert parallel), and for
     multi-pod a leading "pod" axis that shards batch only (params replicate
     across the DCN; gradient all-reduce is the only cross-pod collective).
+    Axes are Auto: ``repro.sharding.specs`` places arrays by name and the
+    compiler propagates the rest.
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh():
-    """Degenerate 1x1 mesh over the real local device (CPU smoke runs)."""
-    return jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

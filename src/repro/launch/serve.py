@@ -1,28 +1,32 @@
 """Serving driver: the taxonomy engine end-to-end on synthetic requests,
 through the unified ``repro.api`` facade.
 
+Without ``--smoke`` the model runs at its published widths (random
+weights from seed 0), which needs an accelerator; ``--smoke`` selects the
+reduced config that runs on a CPU in seconds.
+
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-vl-2b --smoke \
         --requests 16 --scheduler chunked --compression divprune-0.5
 
     # per-request compression mixing (one engine, two strategies; the
     # report includes per-strategy prefill token reduction):
-    PYTHONPATH=src python -m repro.launch.serve \
+    PYTHONPATH=src python -m repro.launch.serve --smoke \
         --compression none,framefusion-0.25
 
     # decoder strategies (all batched; speculative slots share each
     # jitted draft/verify round):
-    PYTHONPATH=src python -m repro.launch.serve --decoder speculative
+    PYTHONPATH=src python -m repro.launch.serve --smoke --decoder speculative
 
     # open-loop async serving: Poisson arrivals at --open-loop req/s
     # (virtual clock) through AsyncLVLMServer, with KV-watermark admission
     # control; the JSON report adds queue-wait and admission counters:
-    PYTHONPATH=src python -m repro.launch.serve --open-loop 2000
+    PYTHONPATH=src python -m repro.launch.serve --smoke --open-loop 2000
 
     # multi-engine routing: N async server replicas behind one Router
     # (--routing round_robin | least_kv | prefix_affinity), SLO-slack
     # deferred queues, optional wall-clock pacing; the report is the
     # fleet-wide ClusterMetrics summary:
-    PYTHONPATH=src python -m repro.launch.serve --replicas 2 \
+    PYTHONPATH=src python -m repro.launch.serve --smoke --replicas 2 \
         --routing prefix_affinity --prefix-cache --shared-prefix 32 \
         --open-loop 2000 --admission-order slack
 
@@ -30,7 +34,7 @@ through the unified ``repro.api`` facade.
     # vision encoder + chunked prefill, hand post-compression KV to
     # decode replicas over the modeled KV link (--roles implies the
     # replica count; the report adds a "disaggregation" block):
-    PYTHONPATH=src python -m repro.launch.serve \
+    PYTHONPATH=src python -m repro.launch.serve --smoke \
         --roles prefill:2,decode:2 --open-loop 2000
 """
 from __future__ import annotations
@@ -44,6 +48,7 @@ import numpy as np
 from repro.api import (AdmissionConfig, EngineConfig, GenerationConfig, LVLM,
                        Request, ROUTING_POLICIES, resolve_compression)
 from repro.configs import ARCHS
+from repro.launch.cache import enable_compile_cache
 
 
 def synth_requests(cfg, n, *, seed=0, prompt_lo=16, prompt_hi=48,
@@ -80,7 +85,9 @@ def parse_roles(spec):
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-vl-2b", choices=ARCHS)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced config (runs on a CPU); default is the "
+                         "published widths")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--scheduler", default="continuous",
                     choices=("static", "continuous", "mlfq", "chunked"))
@@ -150,20 +157,10 @@ def main() -> int:
                          "(repro.control): under KV pressure, degrade "
                          "deferred requests to aggressive compression "
                          "presets instead of queueing them")
-    ap.add_argument("--dry-run", action="store_true",
-                    help="lower/compile decode_32k under the production mesh")
     args = ap.parse_args()
 
-    if args.dry_run:
-        import os
-        import subprocess
-        import sys
-        return subprocess.call(
-            [sys.executable, "-m", "repro.launch.dryrun",
-             "--arch", args.arch, "--shape", "decode_32k"],
-            env=dict(os.environ, PYTHONPATH="src"))
-
-    lvlm = LVLM.from_pretrained(args.arch, smoke=True)
+    enable_compile_cache()
+    lvlm = LVLM.from_pretrained(args.arch, smoke=args.smoke)
     # comma list = per-request mixing: the FIRST preset is the engine
     # default, the rest resolve per-request against the same registry
     # (compression is configured via the facade, never by mutating

@@ -3,10 +3,8 @@
     PYTHONPATH=src python -m repro.launch.train --arch phi4-mini-3.8b \
         --smoke --steps 100 --batch 8 --seq 64
 
-``--smoke`` runs the reduced config on the local device (the container's
-CPU); without it the full config is lowered under the production mesh,
-which on this CPU container only makes sense via ``--dry-run`` (alias of
-launch/dryrun.py for the train_4k shape).
+``--smoke`` runs the reduced config on the local device; without it the
+full config trains on the local device, which needs an accelerator.
 """
 from __future__ import annotations
 
@@ -32,19 +30,7 @@ def main() -> int:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--resume", action="store_true")
-    ap.add_argument("--dry-run", action="store_true",
-                    help="lower/compile train_4k under the production mesh")
     args = ap.parse_args()
-
-    if args.dry_run:
-        # delegated: dryrun.py must own the process (XLA_FLAGS ordering)
-        import os
-        import subprocess
-        import sys
-        return subprocess.call(
-            [sys.executable, "-m", "repro.launch.dryrun",
-             "--arch", args.arch, "--shape", "train_4k"],
-            env=dict(os.environ, PYTHONPATH="src"))
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.vocab:

@@ -116,8 +116,6 @@ def roofline_from_compiled(name: str, compiled, *, chips: int,
     """
     from repro.roofline.hlo_cost import walk_costs
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):      # older jax returns [dict]
-        cost = cost[0]
     walk = walk_costs(compiled.as_text())
     flops = float(walk["flops"])
     byts = max(float(cost.get("bytes accessed", 0.0)),

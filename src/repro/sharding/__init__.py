@@ -1,4 +1,3 @@
-from repro.sharding.compat import abstract_mesh
 from repro.sharding.specs import (
     ShardingRules, param_shardings, cache_shardings, batch_shardings,
     opt_state_shardings, logits_sharding, replicated)

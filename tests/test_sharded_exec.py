@@ -17,7 +17,7 @@ SCRIPT = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs import get_config
     from repro.models import build
     from repro.sharding.specs import (ShardingRules, param_shardings,
@@ -36,7 +36,8 @@ SCRIPT = textwrap.dedent("""
     dec_ref, _ = jax.jit(model.decode_step)(
         params, ref_cache, tokens[:, -1:] * 0 + 7, 12)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = ShardingRules(mesh, fsdp=True)
     psh = param_shardings(rules, model.param_specs())
     sp = jax.device_put(params, psh)

@@ -3,17 +3,16 @@ the roofline HLO-collective parser."""
 import jax
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import ARCHS, get_config
 from repro.models.layers import tree_map_specs
 from repro.models.registry import build
 from repro.roofline.analysis import collective_bytes_from_hlo
-from repro.sharding import abstract_mesh
 from repro.sharding.specs import ShardingRules
 
-SINGLE = abstract_mesh((16, 16), ("data", "model"))
-MULTI = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(spec_tree, rules, pspec_fn):
