@@ -8,10 +8,11 @@
         --collapsed profile.folded
 
 Prints a per-site table -- call count, wall total/self seconds, self
-share, modeled virtual seconds -- sorted by self wall time (where an
-optimization pays off first), and optionally writes the collapsed-stack
-lines (``outer;inner <usec>``) any flamegraph renderer consumes
-(flamegraph.pl, speedscope, inferno).
+share of the stacked sites' self time -- sorted by self wall time (where
+an optimization pays off first), with stackless durations (device waits,
+queue wait, pump host time) and counters listed after it, and optionally
+writes the collapsed-stack lines (``outer;inner <usec>``) any flamegraph
+renderer consumes (flamegraph.pl, speedscope, inferno).
 """
 from __future__ import annotations
 
@@ -29,23 +30,30 @@ def load_profile(path):
 
 
 def report(doc, out=sys.stdout) -> int:
-    sites = doc.get("sites", {})
+    entries = doc.get("sites", {})
+    sites = {k: s for k, s in entries.items() if "wall_total_s" in s}
     if not sites:
         print("no profiled sites in the document", file=out)
         return 1
-    total_self = sum(s["wall_self_s"] for s in sites.values()) or 1.0
+    stacked = {k: s for k, s in sites.items() if not s.get("stackless")}
+    total_self = sum(s["wall_self_s"] for s in stacked.values()) or 1.0
     print(f"profile_report: {len(sites)} site(s), "
           f"{sum(s['count'] for s in sites.values())} calls, "
           f"{total_self:.6f}s self wall", file=out)
     print(f"{'site':>22} {'count':>7} {'wall_total_s':>13} "
-          f"{'wall_self_s':>12} {'self%':>7} {'virtual_s':>10}", file=out)
+          f"{'wall_self_s':>12} {'self%':>7}", file=out)
     order = sorted(sites.items(),
-                   key=lambda kv: kv[1]["wall_self_s"], reverse=True)
+                   key=lambda kv: (bool(kv[1].get("stackless")),
+                                   -kv[1]["wall_self_s"]))
     for name, s in order:
+        share = "-" if s.get("stackless") \
+            else f"{s['wall_self_s'] / total_self:.1%}"
         print(f"{name:>22} {s['count']:>7} {s['wall_total_s']:>13.6f} "
-              f"{s['wall_self_s']:>12.6f} "
-              f"{s['wall_self_s'] / total_self:>6.1%} "
-              f"{s['virtual_s']:>10.6f}", file=out)
+              f"{s['wall_self_s']:>12.6f} {share:>7}", file=out)
+    for name, c in sorted(entries.items()):
+        if name not in sites:
+            print(f"{name:>22} {c['count']:>7} events, total "
+                  f"{c['total']:g}", file=out)
     return 0
 
 

@@ -14,11 +14,13 @@ O002  No event emission inside a Pallas kernel body: tracer calls in a
       trace time (or never, on cached executables) -- they measure
       nothing and poison the zero-overhead-when-off guarantee. Emit
       from the host wrapper around the ``pallas_call``.
-O003  Profiler-site pairing: every ``profiler.site_begin(...)`` must
-      reach a matching ``site_end`` on every CFG path of the SAME
-      function (profiler sites measure a synchronous region, so unlike
-      trace spans they never pair across function boundaries). A leaked
-      begin corrupts the self/total attribution of every enclosing site.
+O003  Profiler-site pairing: every ``profiler.site_begin(...)`` or
+      ``wait_begin(...)`` must reach a matching ``site_end``/``site_drop``
+      /``wait_end`` on every CFG path of the SAME function (profiler
+      sites measure a synchronous region, so unlike trace spans they
+      never pair across function boundaries). A leaked begin corrupts the
+      self/total attribution of every enclosing site. Begins and closes
+      pair by site name (the call's first argument, literal or f-string).
 
 Site matching understands the ``if <x>.enabled:`` guard idiom: the
 guard's ``if`` header is the CFG site, so the infeasible
@@ -73,14 +75,29 @@ def _span_site(stmt: ast.stmt, names) -> bool:
                for n in _own_nodes(stmt))
 
 
+def _site_names(stmt: ast.stmt, names) -> set:
+    """The first arguments (as dumped ASTs: a literal or an f-string) of
+    the calls to ``names`` that ``stmt`` emits, read as `_span_site`
+    reads the statement."""
+    if _is_enabled_guard(stmt):
+        calls = [n for root in stmt.body for n in ast.walk(root)
+                 if isinstance(n, ast.Call)]
+    else:
+        calls = [n for n in _own_nodes(stmt) if isinstance(n, ast.Call)]
+    return {ast.dump(c.args[0]) if c.args else "" for c in calls
+            if _callee(c) in names}
+
+
 def _pairing_findings(rule: Rule, tree: ast.AST, path: str, scopes,
                       begin_calls, close_calls, module_msg: str,
-                      leak_msg: str) -> List[Finding]:
+                      leak_msg: str, by_name: bool = False
+                      ) -> List[Finding]:
     """Shared begin/close pairing walk (O001 trace spans, O003 profiler
     sites): module-pairing scopes require at least one close site in the
     module; per-function scopes run the CFG walk -- no path from a begin
-    site to the function exit may avoid every close site. Message
-    templates take ``{fn}`` (function name) / ``{scope}`` (description)."""
+    site to the function exit may avoid every close site (``by_name``:
+    every close site of the same name). Message templates take ``{fn}``
+    (function name) / ``{scope}`` (description)."""
     out: List[Finding] = []
     for scope in scopes:
         if not path.endswith(scope.path_suffix):
@@ -100,14 +117,20 @@ def _pairing_findings(rule: Rule, tree: ast.AST, path: str, scopes,
             begins = [s for s in body if _span_site(s, begin_calls)]
             if not begins:
                 continue
-            ok = {s for s in body if _span_site(s, close_calls)}
+            closes = [s for s in body if _span_site(s, close_calls)]
             graph = build_cfg(fn)
             for b in begins:
                 if b not in graph.succ:
                     continue                # nested def: out of this walk
-                reaches = graph.path_avoiding(ENTRY, b, ok)
-                leaks = graph.path_avoiding(b, EXIT, ok - {b})
-                if reaches and leaks:
+                if by_name:
+                    groups = [{s for s in closes
+                               if name in _site_names(s, close_calls)}
+                              for name in _site_names(b, begin_calls)]
+                else:
+                    groups = [set(closes)]
+                if any(graph.path_avoiding(ENTRY, b, ok)
+                       and graph.path_avoiding(b, EXIT, ok - {b})
+                       for ok in groups):
                     out.append(rule.finding(
                         path, b.lineno, leak_msg.format(fn=fn.name)))
     return out
@@ -153,8 +176,8 @@ class ProfileSitePairingRule(Rule):
             "module opens profiler sites but contains no site_end "
             "({scope})",
             "profiler site opened here in `{fn}` can reach a function "
-            "exit without site_end -- the open frame corrupts self/"
-            "total attribution for every later site")
+            "exit without its site_end -- the open frame corrupts self/"
+            "total attribution for every later site", by_name=True)
 
 
 def _mentions_tracer(expr: ast.expr) -> bool:

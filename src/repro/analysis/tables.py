@@ -368,17 +368,20 @@ SPAN_SCOPES = [
               "coroutine on every path, including cancellation"),
 ]
 
-# repro.obs.profile hot-path sites (O003). Unlike trace spans, profiler
-# sites NEVER cross a function boundary -- wall time is measured around a
-# synchronous region -- so every scope runs the per-function CFG walk.
-PROFILE_BEGIN_CALLS = ("site_begin",)
-PROFILE_CLOSE_CALLS = ("site_end",)
+# repro.obs.profile hot-path sites and device waits (O003). Unlike trace
+# spans, profiler sites and waits NEVER cross a function boundary -- wall
+# time is measured around a synchronous region -- so every scope runs the
+# per-function CFG walk. (``interval_*`` durations cross functions by
+# design and are not paired here.)
+PROFILE_BEGIN_CALLS = ("site_begin", "wait_begin")
+PROFILE_CLOSE_CALLS = ("site_end", "site_drop", "wait_end")
 
 PROFILE_SCOPES = [
     SpanScope("core/serving/engine.py", False,
-              "profiler sites (prefill_forward, decode launch, compress, "
-              "kv transfer, prefix tier) open and close inside one "
-              "method on every path"),
+              "profiler sites (engine step and its phases, prefill_forward, "
+              "decode launch, compress, kv transfer, prefix tier) and "
+              "device waits open and close inside one method on every "
+              "path"),
     SpanScope("control/controller.py", False,
               "the control_step site opens and closes inside "
               "Controller.on_step on every path"),

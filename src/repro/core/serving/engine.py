@@ -46,7 +46,6 @@ from __future__ import annotations
 
 import dataclasses
 from collections import OrderedDict
-from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -132,6 +131,9 @@ class SamplingEngineDecoder:
 
     def engine_decode(self, eng: "Engine", reqs: List[Request]) -> Dict:
         ec = eng.ec
+        prof = eng.profiler
+        if prof.enabled:
+            prof.site_begin("decode_inputs")
         toks = np.zeros((ec.max_batch, 1), np.int32)
         # fixed-shape decode runs EVERY slot; inactive slots (empty or
         # mid-prefill) must not corrupt real cache entries, so their write
@@ -141,12 +143,19 @@ class SamplingEngineDecoder:
         for r in reqs:
             toks[r._slot, 0] = eng.slot_last_tok[r._slot]
             pos[r._slot] = eng.slot_pos[r._slot]
-        logits, eng.pool = eng._jit_decode(
-            eng.params, eng.pool, jnp.asarray(toks), jnp.asarray(pos))
+        toks, pos = jnp.asarray(toks), jnp.asarray(pos)
+        if prof.enabled:
+            prof.site_end("decode_inputs")
+        logits, eng.pool = eng._jit_decode(eng.params, eng.pool, toks, pos)
         eng.key, k1 = jax.random.split(eng.key)
         temp = 0.0 if self.greedy else ec.temperature
-        nxt = np.asarray(sample_token(k1, logits, temperature=temp,
-                                      top_k=ec.top_k, top_p=ec.top_p))
+        sampled = sample_token(k1, logits, temperature=temp, top_k=ec.top_k,
+                               top_p=ec.top_p)
+        if prof.enabled:
+            prof.wait_begin("wait:decode")
+        nxt = np.asarray(sampled)
+        if prof.enabled:
+            prof.wait_end("wait:decode")
         emitted: Dict[int, List[int]] = {}
         for r in reqs:
             s = r._slot
@@ -269,12 +278,22 @@ class Engine:
         self.migrated_in = 0
         self.migrated_out = 0
 
-        self._jit_prefill = jax.jit(
-            lambda p, b: self.model.prefill(p, b, cache_len=ec.cache_len,
-                                            windowed=self.windowed))
-        self._jit_extend = jax.jit(self.model.extend)
-        self._jit_decode = jax.jit(
-            partial(self.model.decode_step, windowed=self.windowed))
+        # named functions, so that each XLA program carries the name of
+        # the engine call it serves (``jit_engine_prefill``, ...)
+        def engine_prefill(p, batch):
+            return self.model.prefill(p, batch, cache_len=ec.cache_len,
+                                      windowed=self.windowed)
+
+        def engine_extend(p, cache, tokens, start):
+            return self.model.extend(p, cache, tokens, start)
+
+        def engine_decode(p, pool, toks, pos):
+            return self.model.decode_step(p, pool, toks, pos,
+                                          windowed=self.windowed)
+
+        self._jit_prefill = jax.jit(engine_prefill)
+        self._jit_extend = jax.jit(engine_extend)
+        self._jit_decode = jax.jit(engine_decode)
 
         # decoder registry: the configured default plus named per-request
         # strategies; unknown names resolve lazily via repro.api.decoders
@@ -444,6 +463,10 @@ class Engine:
                 " (last position is the inactive-slot scratch)")
         req.arrival = max(req.arrival, self.clock)
         self.waiting.append(req)
+        if self.profiler.enabled:
+            # a request the async server admitted is already waiting
+            # since its admission began: that interval keeps its start
+            self.profiler.interval_begin("queue_wait", req.rid, rid=req.rid)
         if self.tracer.enabled:
             self.tracer.span_begin(
                 "request", req.rid, replica=self.trace_replica,
@@ -527,6 +550,8 @@ class Engine:
                     self._release_request(r)
                     r.state = State.DONE
                     r.aborted = True
+                    if self.profiler.enabled:
+                        self.profiler.interval_drop("queue_wait", rid)
                     self.aborted.append(r)
                     if self.tracer.enabled:
                         # closes the request span AND any open stage span
@@ -704,10 +729,7 @@ class Engine:
             self.profiler.site_begin("kv_transfer")
         self._install_snap(slot, ticket["snap"])
         if self.profiler.enabled:
-            # virtual attribution: the modeled KV-link transfer this
-            # import pays on the target clock (cf. ``ready_at``)
-            self.profiler.site_end(
-                "kv_transfer", vt=self.ec.cost.transfer_time(pos))
+            self.profiler.site_end("kv_transfer")
         self.slot_pos[slot] = pos
         self.slot_last_tok[slot] = ticket["last_tok"]
         self.slot_nv[slot] = ticket["nv"]
@@ -776,9 +798,7 @@ class Engine:
                     self._iter_transfer_cost += self.ec.cost.transfer_time(rk)
                     self.remote_prefix_hits += 1
                     if self.profiler.enabled:
-                        self.profiler.site_end(
-                            "prefix_tier_install",
-                            vt=self.ec.cost.transfer_time(rk))
+                        self.profiler.site_end("prefix_tier_install")
                 return rk, (rsnap, rk)
         if best is not None:
             if touch:
@@ -855,12 +875,13 @@ class Engine:
         n = min(n, len(req.tokens) - req.prefill_done)
         if n <= 0:
             return
-        first_chunk = req.prefill_done == 0
         # hot-path site: the whole chunk (compression, prefix probe and
         # forward) -- nested sites (compress, prefix_tier_*) subtract from
         # this site's SELF time, leaving the forward pass itself
         if self.profiler.enabled:
-            self.profiler.site_begin("prefill_forward")
+            if req.prefill_done == 0:
+                self.profiler.interval_end("queue_wait", req.rid)
+            self.profiler.site_begin("prefill_forward", rid=req.rid)
         comp_name = getattr(req, "_comp_name", None) \
             or self._default_comp_name
         if req.prefill_done == 0:
@@ -894,7 +915,12 @@ class Engine:
                         if getattr(comp, "needs_query", True) else None
                     ve_j, _, _ = comp.compress_prefill(
                         jnp.asarray(ve)[None], query=q)
-                    ve = np.asarray(ve_j[0])
+                    ve_j = ve_j[0]
+                    if self.profiler.enabled:
+                        self.profiler.wait_begin("wait:compress")
+                    ve = np.asarray(ve_j)
+                    if self.profiler.enabled:
+                        self.profiler.wait_end("wait:compress")
                 if self.profiler.enabled:
                     self.profiler.site_end("compress")
                 cnt = self._comp_counts.setdefault(comp_name, [0, 0])
@@ -973,8 +999,13 @@ class Engine:
             self.key, k1 = jax.random.split(self.key)
             _, dec = self._resolve_decoder(req.decoder)
             temp = 0.0 if getattr(dec, "greedy", False) else ec.temperature
-            tok = int(sample_token(k1, logits[:, -1], temperature=temp,
-                                   top_k=ec.top_k, top_p=ec.top_p)[0])
+            first = sample_token(k1, logits[:, -1], temperature=temp,
+                                 top_k=ec.top_k, top_p=ec.top_p)[0]
+            if self.profiler.enabled:
+                self.profiler.wait_begin("wait:prefill")
+            tok = int(first)
+            if self.profiler.enabled:
+                self.profiler.wait_end("wait:prefill")
             req.generated.append(tok)
             req._needs_ttft = True
             self.slot_last_tok[slot] = tok
@@ -996,11 +1027,7 @@ class Engine:
                 self.waiting.remove(req)
             self.running.append(req)
         if self.profiler.enabled:
-            # virtual attribution: the chunk's share of this step's
-            # modeled prefill cost (visual tokens enter on chunk 0)
-            nv_chunk = int(self.slot_nv[slot]) if first_chunk else 0
-            self.profiler.site_end(
-                "prefill_forward", vt=ec.cost.prefill_time(n + nv_chunk))
+            self.profiler.site_end("prefill_forward")
 
     # ------------------------------------------------------ KV compaction --
     def _compact_slot(self, slot: int, selector: str, budget: int) -> None:
@@ -1069,7 +1096,8 @@ class Engine:
             dec = self._decoders[name]
             self._iter_decode_cost = None
             if self.profiler.enabled:
-                self.profiler.site_begin(f"decode:{name}")
+                self.profiler.site_begin(f"decode:{name}", rows=len(group))
+                self.profiler.count("decode_rows", len(group))
             emitted_all.update(dec.engine_decode(self, group))
             if self._iter_decode_cost is None:
                 ctx = float(np.mean([self.slot_pos[r._slot] for r in group]))
@@ -1077,9 +1105,7 @@ class Engine:
             else:
                 cost = self._iter_decode_cost
             if self.profiler.enabled:
-                # per-group launch: wall covers the decoder's jitted
-                # forward(s); virtual is the group's true modeled cost
-                self.profiler.site_end(f"decode:{name}", vt=cost)
+                self.profiler.site_end(f"decode:{name}")
             total_cost += cost
             self.group_costs[name] = self.group_costs.get(name, 0.0) + cost
             if self.tracer.enabled:
@@ -1100,6 +1126,10 @@ class Engine:
     # --------------------------------------------------------------- step --
     def step(self) -> bool:
         """One scheduler iteration. Returns False when fully idle."""
+        prof = self.profiler
+        if prof.enabled:
+            prof.site_begin("engine_step", it=self.iters)
+            prof.site_begin("schedule")
         self.running = [r for r in self.running if r.state != State.DONE]
         visible = [r for r in self.waiting if r.arrival <= self.clock]
         plan = self.sched.plan(visible, self.running)
@@ -1108,7 +1138,11 @@ class Engine:
         # MIGRATING request is frozen until export completes or cancels
         decode_reqs = [r for r in plan.decode if r.state == State.DECODE
                        and getattr(r, "_ready_at", 0.0) <= self.clock]
+        if prof.enabled:
+            prof.site_end("schedule")
         if not plan.prefill and not decode_reqs:
+            if prof.enabled:
+                prof.site_drop("engine_step")     # no work: not a step
             future = [r.arrival for r in self.waiting
                       if r.arrival > self.clock]
             future += [r._ready_at for r in self.running
@@ -1142,6 +1176,8 @@ class Engine:
                                   replica=self.trace_replica,
                                   slot=r._slot, rid=r.rid)
         # stamp times & retire
+        if prof.enabled:
+            prof.site_begin("retire")
         seen, stampable = set(), []
         for r in self.running + [r for r, _ in plan.prefill]:
             if id(r) not in seen:
@@ -1165,8 +1201,12 @@ class Engine:
                                          vt=self.clock,
                                          tokens=len(r.generated))
         self.running = [r for r in self.running if r.state != State.DONE]
+        if prof.enabled:
+            prof.site_end("retire")
         if self.sanitize:
             self._sanitize_check(f"Engine.step (iter {self.iters})")
+        if prof.enabled:
+            prof.site_end("engine_step")
         return True
 
     def run(self, max_iters: int = 100000) -> Dict:

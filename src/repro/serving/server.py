@@ -261,6 +261,9 @@ class AsyncLVLMServer:
         stream.submit_clock = self.engine.clock
         rid = stream.request.rid
         rep = self.engine.trace_replica
+        if self.profiler.enabled:
+            # wall time to the first prefill chunk (Engine ends it there)
+            self.profiler.interval_begin("queue_wait", rid, rid=rid)
         if self.tracer.enabled:
             self.tracer.span_begin("admission_wait", rid, replica=rep,
                                    vt=self.engine.clock)
@@ -272,6 +275,8 @@ class AsyncLVLMServer:
             admitted = await self.admission.admit(stream.request)
         except asyncio.CancelledError:
             self._streams.pop(stream.request.rid, None)
+            if self.profiler.enabled:
+                self.profiler.interval_drop("queue_wait", rid)
             if self.control is not None:
                 self.control.revert(stream.request)
             stream.aborted = True
@@ -282,6 +287,8 @@ class AsyncLVLMServer:
                                        reason="cancelled at admission")
             raise
         if not admitted:
+            if self.profiler.enabled:
+                self.profiler.interval_drop("queue_wait", rid)
             if self.control is not None:
                 self.control.revert(stream.request)
             if self.tracer.enabled:
@@ -457,12 +464,19 @@ class AsyncLVLMServer:
     # ------------------------------------------------------------- pump --
     async def _pump(self) -> None:
         eng = self.engine
+        prof = self.profiler
         try:
             while True:
                 before = eng.clock
                 progressed = False
                 if eng.waiting or eng.running:
+                    if prof.enabled:
+                        # host time since the last step returned: fan-out,
+                        # admission, the clients' turn on the event loop
+                        prof.interval_end("pump_host", id(self))
                     progressed = eng.step()  # one jitted grouped iteration
+                    if prof.enabled and progressed:
+                        prof.interval_begin("pump_host", id(self))
                 self._drain()
                 self._check_disconnects()
                 self.admission.maybe_admit()
@@ -478,7 +492,9 @@ class AsyncLVLMServer:
                     # idle, or every live request is frozen (MIGRATING /
                     # awaiting its KV transfer): park until a submit,
                     # migration completion, or stop wakes the pump --
-                    # never busy-spin
+                    # never busy-spin; parked time is not host time
+                    if prof.enabled:
+                        prof.interval_drop("pump_host", id(self))
                     if self._stopping:
                         return
                     self._wake.clear()

@@ -593,7 +593,7 @@ PROFILE_OK = """
                     self.profiler.site_end("decode:greedy")
                 return 0.0
             if self.profiler.enabled:
-                self.profiler.site_end("decode:greedy", vt=cost)
+                self.profiler.site_end("decode:greedy")
             return cost
     """
 
@@ -615,6 +615,47 @@ def test_o003_leaky_site_flagged():
     fs = lint(bad, ENGINE_PATH, rules=["O003"])
     assert rules_of(fs) == ["O003", "O003"]     # guard header + call site
     assert "self/total attribution" in fs[0].message
+
+
+PROFILE_PHASES = """
+    class Engine:
+        def step(self):
+            if self.profiler.enabled:
+                self.profiler.site_begin("engine_step")
+            plan = self._plan()
+            if not plan:
+                if self.profiler.enabled:
+                    self.profiler.site_drop("engine_step")
+                return False
+            if self.profiler.enabled:
+                self.profiler.wait_begin("wait:decode")
+            self._pull()
+            if self.profiler.enabled:
+                self.profiler.wait_end("wait:decode")
+            if self.profiler.enabled:
+                self.profiler.site_begin("retire")
+            self._retire()
+            if self.profiler.enabled:
+                self.profiler.site_end("retire")
+            if self.profiler.enabled:
+                self.profiler.site_end("engine_step")
+            return True
+    """
+
+
+@pytest.mark.parametrize("close", [
+    None, 'self.profiler.site_end("retire")',
+    'self.profiler.wait_end("wait:decode")',
+    'self.profiler.site_drop("engine_step")'])
+def test_o003_pairs_each_site_by_name(close):
+    """A site or wait whose own close is missing is flagged even where
+    another site's close follows it on every path."""
+    if close is None:
+        assert lint(PROFILE_PHASES, ENGINE_PATH, rules=["O003"]) == []
+        return
+    bad = PROFILE_PHASES.replace(close, "pass")
+    fs = lint(bad, ENGINE_PATH, rules=["O003"])
+    assert rules_of(fs) == ["O003", "O003"]     # guard header + call site
 
 
 def test_renaming_engine_site_closes_trips_o003():

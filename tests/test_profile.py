@@ -102,7 +102,9 @@ def test_unprofiled_hot_path_makes_no_profiler_calls(lvlm, monkeypatch):
         raise AssertionError("profiler method called on the unprofiled "
                              "path")
 
-    for name in ("site_begin", "site_end"):
+    for name in ("site_begin", "site_end", "site_drop", "wait_begin",
+                 "wait_end", "interval_begin", "interval_end",
+                 "interval_drop", "count"):
         monkeypatch.setattr(NullProfiler, name, boom)
     res = lvlm.serve(_reqs(_prompts(3, seed=1)), engine_cfg=_ec(), gen=GEN)
     assert res.engine.profiler is NULL_PROFILER
@@ -118,7 +120,9 @@ def test_profiled_run_is_bit_identical_at_temp0(lvlm):
     """Profiling only reads clocks: same tokens, sanitizer clean, and
     every expected hot-path site class observed on a disaggregated
     fleet (prefill forward on the prefill replica, kv export/transfer
-    across the link, decode launches on the decode replica)."""
+    across the link, decode launches on the decode replica, the step's
+    phases and device waits, each request's queue wait, the pumps' host
+    time)."""
     prompts = _prompts(4, seed=3)
     ref = _drive_all(lvlm.serve_cluster(2, _ec(cost=COST), gen=GEN,
                                         roles=["prefill", "decode"]),
@@ -131,14 +135,21 @@ def test_profiled_run_is_bit_identical_at_temp0(lvlm):
     assert got == ref
     snap = prof.snapshot()
     for site in ("prefill_forward", "decode:greedy", "kv_export",
-                 "kv_transfer"):
+                 "kv_transfer", "engine_step", "schedule", "decode_inputs",
+                 "retire", "wait:decode", "wait:prefill", "queue_wait",
+                 "pump_host"):
         assert snap[site]["count"] > 0, site
         assert snap[site]["wall_total_s"] >= snap[site]["wall_self_s"] >= 0
         assert sum(n for _, n in snap[site]["wall_buckets"]) \
             == snap[site]["count"]
-    # virtual attribution flows from the cost model, not the wall clock
-    assert snap["kv_transfer"]["virtual_s"] > 0.0
-    assert snap["decode:greedy"]["virtual_s"] > 0.0
+    assert snap["queue_wait"]["count"] == len(prompts)
+    for site in ("wait:decode", "wait:prefill", "queue_wait", "pump_host"):
+        assert snap[site]["stackless"], site
+        assert snap[site]["wall_self_s"] == snap[site]["wall_total_s"]
+    assert not snap["engine_step"]["stackless"]
+    # every decode row is one token served after the first
+    assert snap["decode_rows"]["count"] == snap["decode:greedy"]["count"]
+    assert snap["decode_rows"]["total"] == len(prompts) * (MAX_NEW - 1)
 
 
 # ------------------------------------------------- attribution mechanics --
@@ -156,22 +167,183 @@ def test_profiler_self_total_nesting():
     t[0] = 1.0
     prof.site_begin("inner")
     t[0] = 3.0
-    prof.site_end("inner", vt=0.5)
+    prof.site_end("inner")
     t[0] = 4.0
-    prof.site_end("outer", vt=1.5)
+    prof.site_end("outer")
     snap = prof.snapshot()
     assert snap["outer"]["wall_total_s"] == pytest.approx(4.0)
     assert snap["outer"]["wall_self_s"] == pytest.approx(2.0)
     assert snap["inner"]["wall_total_s"] == pytest.approx(2.0)
     assert snap["inner"]["wall_self_s"] == pytest.approx(2.0)
-    assert snap["outer"]["virtual_s"] == pytest.approx(1.5)
-    assert snap["inner"]["virtual_s"] == pytest.approx(0.5)
+    assert snap["outer"]["count"] == snap["inner"]["count"] == 1
+    assert not snap["outer"]["stackless"]
     lines = prof.collapsed()
     assert "outer 2000000" in lines
     assert "outer;inner 2000000" in lines
     rec = prof.bench_record()
     assert rec["schema_version"] == 1
     assert rec["sites"]["outer"]["count"] == 1
+
+
+def _prefill_shaped(prof, t, waits):
+    """A step holding a prefill whose compression and first token each
+    wait on the device; ``waits`` times those waits or leaves them be."""
+    def tick(dt):
+        t[0] += dt
+
+    prof.site_begin("engine_step")
+    tick(1.0)
+    prof.site_begin("prefill_forward", rid=7)
+    tick(1.0)
+    prof.site_begin("compress")
+    tick(0.5)
+    if waits:
+        prof.wait_begin("wait:compress")
+    tick(2.0)
+    if waits:
+        prof.wait_end("wait:compress")
+    prof.site_end("compress")
+    tick(1.0)
+    if waits:
+        prof.wait_begin("wait:prefill")
+    tick(3.0)
+    if waits:
+        prof.wait_end("wait:prefill")
+    prof.site_end("prefill_forward")
+    prof.site_end("engine_step")
+
+
+def test_waits_leave_site_times_unchanged():
+    """Device waits are stackless: timing them moves no site's self or
+    total time (``prefill_call_ms`` reads ``prefill_forward``'s self
+    time, ``compress_call_ms`` ``compress``'s total) and no collapsed
+    stack."""
+    plain, t0 = _manual_profiler()
+    _prefill_shaped(plain, t0, waits=False)
+    timed, t1 = _manual_profiler()
+    _prefill_shaped(timed, t1, waits=True)
+    a, b = plain.snapshot(), timed.snapshot()
+    for site in ("engine_step", "prefill_forward", "compress"):
+        for key in ("count", "wall_total_s", "wall_self_s"):
+            assert a[site][key] == b[site][key], (site, key)
+    assert b["prefill_forward"]["wall_self_s"] == pytest.approx(5.0)
+    assert b["compress"]["wall_total_s"] == pytest.approx(2.5)
+    assert b["wait:compress"]["wall_total_s"] == pytest.approx(2.0)
+    assert b["wait:prefill"]["wall_total_s"] == pytest.approx(3.0)
+    assert b["wait:prefill"]["stackless"]
+    assert plain.collapsed() == timed.collapsed()
+
+
+def test_site_drop_and_intervals():
+    prof, t = _manual_profiler()
+    prof.site_begin("outer")
+    prof.site_begin("idle")
+    t[0] = 2.0
+    prof.site_drop("idle")                  # not recorded: outer's self
+    prof.site_end("outer")
+    prof.interval_begin("queue_wait", 1)
+    t[0] = 3.0
+    prof.interval_begin("queue_wait", 2)
+    prof.interval_begin("queue_wait", 1)    # already open: keeps start
+    t[0] = 4.0
+    prof.interval_end("queue_wait", 1)
+    prof.interval_drop("queue_wait", 2)     # left before it ended
+    prof.interval_end("queue_wait", 2)      # nothing open: no-op
+    prof.wait_begin("wait:decode")
+    t[0] = 9.0
+    prof.wait_begin("wait:decode")          # a stale wait is replaced
+    t[0] = 10.0
+    prof.wait_end("wait:decode")
+    snap = prof.snapshot()
+    assert "idle" not in snap
+    assert snap["outer"]["wall_self_s"] == pytest.approx(2.0)
+    assert snap["queue_wait"]["count"] == 1
+    assert snap["queue_wait"]["wall_total_s"] == pytest.approx(2.0)
+    assert snap["wait:decode"]["count"] == 1
+    assert snap["wait:decode"]["wall_total_s"] == pytest.approx(1.0)
+    assert prof.collapsed() == ["outer 2000000"]
+
+
+def test_decode_sites_count_decode_launches(lvlm, monkeypatch):
+    """``decode_batch_mean`` counts launches by the ``decode:`` sites:
+    one per decoder launch, and no other site or counter starts with
+    ``decode:``. ``decode_rows`` counts each launch's rows."""
+    from repro.core.serving.engine import SamplingEngineDecoder
+    launches = []
+    inner = SamplingEngineDecoder.engine_decode
+
+    def counted(self, eng, reqs):
+        launches.append(len(reqs))
+        return inner(self, eng, reqs)
+
+    monkeypatch.setattr(SamplingEngineDecoder, "engine_decode", counted)
+    prof = Profiler()
+    res = lvlm.serve(_reqs(_prompts(3, seed=8), new=5), engine_cfg=_ec(),
+                     gen=GEN, profile=prof)
+    assert res.stats["finished"] == 3
+    snap = prof.snapshot()
+    decode_sites = [k for k in snap if k.startswith("decode:")]
+    assert decode_sites == ["decode:greedy"]
+    assert snap["decode:greedy"]["count"] == len(launches) > 0
+    for name in ("decode_inputs", "wait:decode", "decode_rows"):
+        assert snap[name]["count"] == len(launches), name
+    assert snap["decode_rows"]["total"] == sum(launches) == 3 * 4
+    assert snap["engine_step"]["count"] == res.engine.iters
+
+
+@pytest.fixture(scope="module")
+def vlm():
+    return LVLM.from_pretrained("qwen2-vl-2b", smoke=True)
+
+
+def test_profiled_serve_async_spans_land_in_the_jax_trace(vlm, tmp_path):
+    """With a ``jax.profiler`` trace recording, each site is a
+    ``repro:<site>`` span on the host plane of the same trace as the
+    device's operations: prefills (with their request id) and decode
+    input puts inside engine steps, one queue wait per request."""
+    import jax
+    from jax.profiler import ProfileData
+
+    cfg = vlm.cfg
+    rng = np.random.RandomState(9)
+    reqs = [Request(rid=i, tokens=list(rng.randint(1, cfg.vocab_size, 9)),
+                    max_new_tokens=3, compression="fastv-0.5",
+                    visual_embeds=rng.randn(cfg.num_visual_tokens,
+                                            cfg.d_model).astype(np.float32))
+            for i in range(3)]
+    prof = Profiler()
+    server = vlm.serve_async(EngineConfig(max_batch=2, cache_len=96,
+                                          temperature=0.0),
+                             GEN, profile=prof)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        got = _drive_all(server, reqs)
+    finally:
+        jax.profiler.stop_trace()
+    assert all(len(o) == 3 for o in got.values())
+    path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    spans = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro:"):
+                    spans.setdefault(e.name[len("repro:"):], []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)))
+    steps = spans["engine_step"]
+
+    def in_a_step(span):
+        return any(a <= span[0] and span[1] <= b for a, b, _ in steps)
+
+    assert len(steps) == prof.snapshot()["engine_step"]["count"]
+    assert sorted(s[2]["rid"] for s in spans["prefill_forward"])         == [0, 1, 2]
+    for name in ("prefill_forward", "decode_inputs", "compress",
+                 "wait:compress", "wait:prefill", "wait:decode"):
+        assert spans[name] and all(in_a_step(s) for s in spans[name]), name
+    assert all(s[2]["rows"] >= 1 for s in spans["decode:greedy"])
+    assert sorted(s[2]["rid"] for s in spans["queue_wait"]) == [0, 1, 2]
+    assert spans["pump_host"]
+    assert not any(in_a_step(s) for s in spans["pump_host"])
 
 
 def test_profiler_log_buckets():
@@ -248,14 +420,21 @@ def test_metrics_snapshot_renders_profile_once_per_fleet(lvlm):
 def test_profile_families_helper():
     prof, t = _manual_profiler()
     prof.site_begin("a")
-    t[0] = 0.002
-    prof.site_end("a", vt=0.25)
+    t[0] = 0.25
+    prof.site_end("a")
+    prof.count("rows", 3)
+    prof.count("rows", 5)
     prom = PromText()
     profile_families(prom, prof, labels={"cluster": "x"})
     text = prom.render()
     assert 'cluster="x"' in text
-    assert "# TYPE repro_profile_virtual_seconds histogram" in text
-    assert 'repro_profile_virtual_seconds_sum{cluster="x",site="a"} 0.25' \
+    assert "virtual" not in text
+    assert 'repro_profile_wall_seconds_sum{cluster="x",site="a"} 0.25' \
+        in text
+    assert "# TYPE repro_profile_events_total counter" in text
+    assert 'repro_profile_events_total{cluster="x",counter="rows"} 2.0' \
+        in text
+    assert 'repro_profile_counted_total{cluster="x",counter="rows"} 8.0' \
         in text
 
 
@@ -324,7 +503,8 @@ def test_serving_baseline_has_profile_block():
     assert doc["schema_version"] == 1
     sites = doc["profile"]["sites"]
     assert sites["prefill_forward"]["count"] > 0
-    assert sites["kv_transfer"]["virtual_s"] > 0.0
+    assert sites["kv_transfer"]["wall_total_s"] > 0.0
+    assert all("virtual_s" not in s for s in sites.values())
 
 
 # ---------------------------------------------------------- report tools --
@@ -338,7 +518,8 @@ def test_profile_report_table_and_collapsed(tmp_path, capsys):
     t[0] = 3.0
     prof.site_end("compress")
     t[0] = 4.0
-    prof.site_end("prefill_forward", vt=0.125)
+    prof.site_end("prefill_forward")
+    prof.count("decode_rows", 4)
     p = str(tmp_path / "profile.json")
     prof.write_json(p)
     pr = _load_script("profile_report")
@@ -346,6 +527,7 @@ def test_profile_report_table_and_collapsed(tmp_path, capsys):
     assert pr.main([p, "--collapsed", folded]) == 0
     out = capsys.readouterr().out
     assert "prefill_forward" in out and "compress" in out
+    assert "decode_rows" in out and "total 4" in out
     lines = open(folded).read().splitlines()
     assert "prefill_forward;compress 2000000" in lines
     assert "prefill_forward 2000000" in lines
