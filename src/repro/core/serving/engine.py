@@ -293,7 +293,9 @@ class Engine:
 
         self._jit_prefill = jax.jit(engine_prefill)
         self._jit_extend = jax.jit(engine_extend)
-        self._jit_decode = jax.jit(engine_decode)
+        # the pool is donated: the decode step writes its new rows into
+        # it in place, and every caller rebinds ``pool`` to the result
+        self._jit_decode = jax.jit(engine_decode, donate_argnums=(1,))
 
         # decoder registry: the configured default plus named per-request
         # strategies; unknown names resolve lazily via repro.api.decoders

@@ -252,18 +252,28 @@ def _cache_write_prefill(cache, new_k, new_v, windowed):
     return dict(cache, k=k, v=v)
 
 
-def _cache_write_decode(cache, new_k, new_v, pos, windowed):
-    """Write ONE token per request at per-request position ``pos [B]``."""
-    length = cache["k"].shape[1]
-    b = new_k.shape[0]
-    bidx = jnp.arange(b)
+def write_decode_rows(cache, new, pos, layer=None):
+    """Write ONE token per request, ``new[name] [B,...]``, at per-request
+    position ``pos [B]``: into one layer's cache [B,S,...], or, given
+    ``layer``, into the stacked cache [L,B,S,...] at (layer, row, slot).
+    Either way only B rows are written, so a donated pool is updated in
+    place. A ring (``slot_pos`` in the cache) records ``pos`` per slot."""
+    lead = () if layer is None else (layer,)
+    windowed = "slot_pos" in cache
+    length = cache[next(iter(new))].shape[len(lead) + 1]
     slot = jnp.mod(pos, length) if windowed else pos
-    k = cache["k"].at[bidx, slot].set(new_k[:, 0])
-    v = cache["v"].at[bidx, slot].set(new_v[:, 0])
+    idx = lead + (jnp.arange(pos.shape[0]), slot)
     if windowed:
-        sp = cache["slot_pos"].at[bidx, slot].set(pos.astype(jnp.int32))
-        return dict(cache, k=k, v=v, slot_pos=sp)
-    return dict(cache, k=k, v=v)
+        new = dict(new, slot_pos=pos.astype(jnp.int32))
+    return dict(cache, **{n: cache[n].at[idx].set(v) for n, v in new.items()})
+
+
+def _layer_of(cache, layer):
+    """One layer's cache: ``cache`` itself, or layer ``layer`` of a stack."""
+    if layer is None:
+        return cache
+    return {n: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for n, a in cache.items()}
 
 
 def prefill_into_cache(p, x, cos, sin, cfg, cache, *, window=0,
@@ -403,20 +413,24 @@ def mla_append_attention(p, x, cos, sin, cfg, cache, start, *, window=0):
     return out, cache
 
 
-def decode_attention(p, x, cos, sin, cfg, cache, pos, *, window=0):
-    """One-token decode vs cache. x [B,1,d]; pos [B] per-request int32."""
+def decode_attention(p, x, cos, sin, cfg, cache, pos, *, window=0,
+                     layer=None):
+    """One-token decode vs cache. x [B,1,d]; pos [B] per-request int32.
+
+    ``cache`` is one layer's, or with ``layer`` the stacked cache; the new
+    token is written first (``write_decode_rows``), then attended."""
     b = x.shape[0]
     pos = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(pos, jnp.int32)), (b,))
     q, k, v = qkv_proj(p, x)
     if cos is not None:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-    windowed = "slot_pos" in cache
-    cache = _cache_write_decode(cache, k, v, pos, windowed)
-    k_pos = (cache["slot_pos"] if windowed
-             else jnp.arange(cache["k"].shape[1], dtype=jnp.int32))
+    cache = write_decode_rows(cache, {"k": k[:, 0], "v": v[:, 0]}, pos, layer)
+    lc = _layer_of(cache, layer)
+    k_pos = (lc["slot_pos"] if "slot_pos" in lc
+             else jnp.arange(lc["k"].shape[1], dtype=jnp.int32))
     qg = _grouped(q, cfg.num_kv_heads)
-    o = simple_sdpa(qg, cache["k"], cache["v"], q_pos=pos[:, None],
+    o = simple_sdpa(qg, lc["k"], lc["v"], q_pos=pos[:, None],
                     k_pos=k_pos, causal=True, window=window)
     return out_proj(p, o), cache
 
@@ -500,29 +514,27 @@ def mla_full_attention(p, x, cos, sin, cfg, *, window=0, positions=None,
     return (out, cache) if cache is not None else out
 
 
-def mla_decode_attention(p, x, cos, sin, cfg, cache, pos, *, window=0):
+def mla_decode_attention(p, x, cos, sin, cfg, cache, pos, *, window=0,
+                         layer=None):
     """Absorbed-form MLA decode: attention runs in the latent space.
 
     The per-head key projection wk_b is absorbed into the query and wv_b
     into the output -- the cache holds only [B,S,r] + [B,S,rope]; this IS
     the survey's dim-2 cache compression realized architecturally.
-    pos: [B] per-request int32 (or scalar, broadcast).
+    pos: [B] per-request int32 (or scalar, broadcast). ``cache`` and
+    ``layer`` as in ``decode_attention``.
     """
     b = x.shape[0]
     pos = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(pos, jnp.int32)), (b,))
     q_nope, q_rope = _mla_q(p, x, cfg, cos, sin)       # [B,1,H,*]
     ckv_t, k_rope_t = _mla_latent(p, x, cfg, cos, sin)  # [B,1,r],[B,1,rope]
-    windowed = "slot_pos" in cache
-    length = cache["ckv"].shape[1]
-    bidx = jnp.arange(b)
-    slot = jnp.mod(pos, length) if windowed else pos
-    cache = dict(cache,
-                 ckv=cache["ckv"].at[bidx, slot].set(ckv_t[:, 0]),
-                 k_rope=cache["k_rope"].at[bidx, slot].set(k_rope_t[:, 0]))
-    if windowed:
-        cache["slot_pos"] = cache["slot_pos"].at[bidx, slot].set(pos)
-        k_pos = cache["slot_pos"]                      # [B,S]
+    cache = write_decode_rows(
+        cache, {"ckv": ckv_t[:, 0], "k_rope": k_rope_t[:, 0]}, pos, layer)
+    lc = _layer_of(cache, layer)
+    if "slot_pos" in lc:
+        k_pos = lc["slot_pos"]                         # [B,S]
     else:
+        length = lc["ckv"].shape[1]
         k_pos = jnp.broadcast_to(jnp.arange(length, dtype=jnp.int32)[None],
                                  (b, length))
     # absorb wk_b into q: [B,1,H,nope] x [r,H,nope] -> [B,1,H,r]
@@ -530,16 +542,16 @@ def mla_decode_attention(p, x, cos, sin, cfg, cache, pos, *, window=0):
                        preferred_element_type=jnp.float32)
     scale = 1.0 / ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5)
     s_lat = jnp.einsum("bshr,bcr->bhsc", q_lat,
-                       cache["ckv"].astype(jnp.float32))
+                       lc["ckv"].astype(jnp.float32))
     s_rope = jnp.einsum("bshe,bce->bhsc", q_rope.astype(jnp.float32),
-                        cache["k_rope"].astype(jnp.float32))
+                        lc["k_rope"].astype(jnp.float32))
     s = (s_lat + s_rope) * scale
     valid = (k_pos >= 0) & (k_pos <= pos[:, None])     # [B,S]
     if window:
         valid = valid & (k_pos > (pos - window)[:, None])
     s = jnp.where(valid[:, None, None], s, NEG_INF)
     pr = jax.nn.softmax(s, axis=-1)
-    o_lat = jnp.einsum("bhsc,bcr->bshr", pr, cache["ckv"].astype(jnp.float32))
+    o_lat = jnp.einsum("bhsc,bcr->bshr", pr, lc["ckv"].astype(jnp.float32))
     o = jnp.einsum("bshr,rhe->bshe", o_lat, p["wv_b"].astype(jnp.float32))
     out = jnp.einsum("bshe,hed->bsd", o.astype(x.dtype), p["wo"],
                      preferred_element_type=jnp.float32).astype(x.dtype)

@@ -2,7 +2,8 @@
 
 Production path scans over layers with *stacked* params (MaxText-style):
 each homogeneous segment of the network is one ``lax.scan`` whose xs are the
-stacked layer params (and the stacked per-layer cache for prefill/decode).
+stacked layer params (and the stacked per-layer cache for prefill; decode
+carries the stack and writes its new rows in place).
 This keeps HLO size O(1) in depth for the 88--96 layer archs.
 
 An unscanned *introspection* path (``scan=False``) runs a Python loop and
@@ -129,16 +130,19 @@ def _dense_layer_prefill(cfg, p, x, cos, sin, cache, *, positions, window,
 
 
 def _dense_layer_decode(cfg, p, x, cos, sin, cache, pos, *, window,
-                        moe_cap=None, weight_stationary=False):
+                        moe_cap=None, weight_stationary=False, layer=None):
+    """One layer's decode. ``cache`` is that layer's, or with ``layer`` the
+    stacked cache, whose layer ``layer`` gets the new token in place."""
     if weight_stationary:
         x = L.constrain_replicated(x)
     h = L.apply_norm(p["ln1"], x, cfg.norm)
     if cfg.use_mla:
         a, cache = attn.mla_decode_attention(p["attn"], h, cos, sin, cfg,
-                                             cache, pos, window=window)
+                                             cache, pos, window=window,
+                                             layer=layer)
     else:
         a, cache = attn.decode_attention(p["attn"], h, cos, sin, cfg, cache,
-                                         pos, window=window)
+                                         pos, window=window, layer=layer)
     x = x + a
     h = L.apply_norm(p["ln2"], x, cfg.norm)
     if cfg.num_experts and "moe" in p:
@@ -747,26 +751,29 @@ class Model:
         cos, sin = self._cos_sin(b, positions)
 
         if cfg.family in ("dense", "vlm", "moe"):
-            def body(carry, xs):
-                lp, lcache = xs
-                x, lcache = _dense_layer_decode(
-                    cfg, lp, carry, cos, sin, lcache, pos, window=window,
-                    moe_cap=moe_cap, weight_stationary=weight_stationary)
-                return x, lcache
+            # the stacked cache rides in the carry, not in xs/ys: each
+            # layer writes its B new rows into it in place, so a donated
+            # pool is never copied (as ys it is written out whole)
+            def scan_layers(lcfg, lparams, stack, x, **kw):
+                def body(carry, xs):
+                    x, stack = carry
+                    lp, i = xs
+                    return _dense_layer_decode(
+                        lcfg, lp, x, cos, sin, stack, pos, window=window,
+                        layer=i, **kw), None
+                n = jax.tree.leaves(stack)[0].shape[0]
+                (x, stack), _ = jax.lax.scan(
+                    body, (x, stack),
+                    (lparams, jnp.arange(n, dtype=jnp.int32)))
+                return x, stack
             if cfg.family == "moe" and cfg.first_k_dense_layers:
-                dense_cfg = cfg.with_(num_experts=0)
-
-                def dbody(carry, xs):
-                    lp, lcache = xs
-                    x, lcache = _dense_layer_decode(
-                        dense_cfg, lp, carry, cos, sin, lcache, pos,
-                        window=window)
-                    return x, lcache
-                x, dcache = jax.lax.scan(
-                    dbody, x, (params["dense_layers"], cache["dense_layers"]))
+                x, dcache = scan_layers(cfg.with_(num_experts=0),
+                                        params["dense_layers"],
+                                        cache["dense_layers"], x)
                 cache = dict(cache, dense_layers=dcache)
-            x, lcache = jax.lax.scan(body, x,
-                                     (params["layers"], cache["layers"]))
+            x, lcache = scan_layers(cfg, params["layers"], cache["layers"], x,
+                                    moe_cap=moe_cap,
+                                    weight_stationary=weight_stationary)
             cache = dict(cache, layers=lcache)
         elif cfg.family == "ssm":
             def body(carry, xs):

@@ -7,6 +7,9 @@ memory, a program that does not fit the device. The topology is described
 inside a fixture, never at import, so every test worker collects the same
 tests and only the worker that runs this file loads the TPU compiler.
 """
+import math
+import re
+
 import pytest
 
 import jax
@@ -99,6 +102,44 @@ def test_engine_decode_step_compiles(engine, on_chip):
         on_chip(jax.ShapeDtypeStruct((MAX_BATCH, 1), jnp.int32)),
         on_chip(jax.ShapeDtypeStruct((MAX_BATCH,), jnp.int32))).compile()
     _fits(compiled)
+
+
+def _whole_layer_writes(hlo: str, stack_shape) -> list:
+    """Instructions of an optimized HLO module that write a whole layer of
+    a stacked cache leaf, or the whole stack: a dynamic-update-slice or
+    scatter whose update holds a layer or more, or a copy of the stack.
+    The in-place row write (a scatter of B rows into the carried stack)
+    is none of these."""
+    inst = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                      r"([\w-]+)\((.*?)\)", re.M)
+    dims, found = {}, []
+    for name, shape, op, args in inst.findall(hlo):
+        dims[name] = tuple(int(d) for d in shape.split(",") if d)
+        operands = re.findall(r"%([\w.-]+)", args)
+        update = {"dynamic-update-slice": 1, "scatter": 2}.get(op)
+        if update is not None and len(operands) > update:
+            size = math.prod(dims.get(operands[update], ()))
+            if size >= math.prod(stack_shape[1:]):
+                found.append(name)
+        if op in ("copy", "copy-start") and dims[name] == tuple(stack_shape):
+            found.append(name)
+    return found
+
+
+def test_engine_decode_writes_pool_in_place(engine, on_chip):
+    """The decode program takes the pool by donation and writes only the
+    new rows: XLA aliases the whole pool to the output, and no op writes a
+    layer of it back whole."""
+    compiled = engine._jit_decode.lower(
+        on_chip(engine.params), on_chip(engine.pool),
+        on_chip(jax.ShapeDtypeStruct((MAX_BATCH, 1), jnp.int32)),
+        on_chip(jax.ShapeDtypeStruct((MAX_BATCH,), jnp.int32))).compile()
+    pool = jax.tree.leaves(engine.pool)
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.nbytes for a in pool)
+    hlo = compiled.as_text()
+    for shape in {a.shape for a in pool}:
+        assert _whole_layer_writes(hlo, shape) == [], shape
 
 
 def _kernel_cases():
