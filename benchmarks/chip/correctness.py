@@ -1,8 +1,9 @@
 """What decides ``correct``: served tokens against the float32 reference.
 
 After the window, a sample of finished requests drawn from the seed (the
-one with the longest output always among them) is run through
-``reference.py`` with its prompt and the tokens the program served. At
+one with the longest output always among them) is run through the
+configuration's reference module (``references/<name>.py``) with its
+prompt and the tokens the program served. At
 every position the number read is the gap by which the served token's
 reference logit lies below the reference's best logit there; a run is
 correct when the widest gap of the sample is within the cell's limit
@@ -66,28 +67,28 @@ def _padded(xs: Sequence[int], n: int) -> jax.Array:
     return jnp.asarray(out)
 
 
-def gaps(m: Dict, params, reqs: Sequence[Served], *, visual_kept: int,
-         pad_len: int, max_served: int, quant: str = "none"
-         ) -> List[np.ndarray]:
+def gaps(ref, m: Dict, params, reqs: Sequence[Served], *,
+         visual_kept: int, pad_len: int, max_served: int,
+         quant: str = "none") -> List[np.ndarray]:
     """Per request, the gap of each served token (``quant="none"``) or of
-    the token the control puts first (``quant="fp8"``)."""
+    the token the control puts first (``quant="fp8"``), by the reference
+    module ``ref``'s logits."""
     out = []
     for r in reqs:
         vis = (None if r.visual is None
                else reference.select_visual(r.visual, visual_kept))
-        ref = reference.served_logits(m, params, r.tokens, vis, r.served,
-                                      pad_len, max_served)
+        logits = ref.served_logits(m, params, r.tokens, vis, r.served,
+                                   pad_len, max_served)
         if quant == "none":
             toks = _padded(r.served, max_served)
         else:
-            ctl = reference.served_logits(m, params, r.tokens, vis,
-                                          r.served, pad_len, max_served,
-                                          quant=quant)
+            ctl = ref.served_logits(m, params, r.tokens, vis, r.served,
+                                    pad_len, max_served, quant=quant)
             toks = jnp.argmax(ctl, axis=-1).astype(jnp.int32)
             del ctl
-        g = np.asarray(_gap_at(ref, toks))[: len(r.served)]
+        g = np.asarray(_gap_at(logits, toks))[: len(r.served)]
         out.append(g)
-        del ref
+        del logits
     return out
 
 

@@ -3,7 +3,9 @@
 A cell of ``BENCHMARK.json`` names a configuration (``configs/<name>.json``)
 and a traffic mix (``traffic/<name>.json``); its limits are in
 ``limits/<cell>.json`` and each per-layer metric is read by
-``metrics/<metric>.py``. Nothing here names a cell, a mix or a metric.
+``metrics/<metric>.py``. The configuration names its reference module
+(``references/<name>.py``: layer equations, weight layout, work counts).
+Nothing here names a cell, a mix, a metric or a layer's equations.
 
 The window drives ``LVLM.serve_async`` (``AsyncLVLMServer`` over
 ``Engine.step``) greedily at temperature 0. Each request is a coroutine
@@ -16,6 +18,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -29,23 +32,14 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import correctness, flops, peaks, stats, trace_reduce, traffic, weights
+from . import correctness, peaks, stats, trace_reduce, traffic, weights
 
 ROOT = Path(__file__).resolve().parent
 REPO = ROOT.parents[1]
 
 DRAIN_S = 60.0          # how long past the close a due request may take
 TRACE_S = 3.0           # the traced part of a --trace 1 window, at most
-
-# published key -> ModelConfig field, for the keys the program takes
-_PROGRAM_KEYS = {
-    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings",
-    "torch_dtype": "dtype", "visual_tokens": "num_visual_tokens",
-}
+DEFAULT_REFERENCE = "dense_gqa"   # a configuration without "reference"
 
 
 class NoChip(RuntimeError):
@@ -75,14 +69,37 @@ def applies(metric: Dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def load_reader(root: Path, name: str):
-    """The module ``metrics/<name>.py`` with its ``read(ctx)``."""
-    path = Path(root) / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chip_metric_{name.replace('.', '_')}", path)
+def _load(path: Path, module: str):
+    spec = importlib.util.spec_from_file_location(module, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(root: Path, name: str):
+    """The module ``metrics/<name>.py`` with its ``read(ctx)``."""
+    return _load(Path(root) / "metrics" / f"{name}.py",
+                 f"chip_metric_{name.replace('.', '_')}")
+
+
+@functools.lru_cache(maxsize=None)
+def load_reference(root: Path, name: str):
+    """The module ``references/<name>.py``: a configuration's layer
+    equations, weight layout and work counts (``references/dense_gqa.py``
+    states what a module gives). Loaded once a process, so its jitted
+    programs are traced once."""
+    path = Path(root) / "references" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no reference module {name!r}: {path} "
+                                "does not exist")
+    return _load(path, f"chip_reference_{name}")
+
+
+def reference_of(conf: Dict, root: Path = ROOT):
+    """The reference module the configuration names, ``dense_gqa`` where
+    it names none."""
+    return load_reference(Path(root),
+                          conf.get("reference", DEFAULT_REFERENCE))
 
 
 def seed32(seed: int) -> int:
@@ -97,17 +114,12 @@ def served_model(conf: Dict) -> Dict:
     return {**conf["model"], **conf.get("departures", {}).get("served", {})}
 
 
-def program_config(conf: Dict):
-    """The program's ``ModelConfig`` with every size the file states."""
+def program_config(conf: Dict, ref):
+    """The program's ``ModelConfig`` with every size the file states, as
+    the reference module ``ref`` maps them."""
     from repro.configs import get_config
-    m = served_model(conf)
-    over = {_PROGRAM_KEYS[k]: v for k, v in m.items() if k in _PROGRAM_KEYS}
-    scaling = m.get("rope_scaling") or {}
-    if scaling.get("type") == "mrope":
-        over["mrope_sections"] = tuple(scaling["mrope_section"])
-    if m.get("hidden_act", "silu") != "silu":
-        raise ValueError("only SwiGLU (silu) MLPs are served")
-    return get_config(conf["arch"]).with_(**over)
+    return get_config(conf["arch"]).with_(
+        **ref.program_fields(served_model(conf)))
 
 
 # ------------------------------------------------------------- records --
@@ -228,6 +240,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     bench = load_benchmark(repo)
     cell = cell_of(bench, workload)
     conf = config_of(bench, repo, cell["config"])
+    ref = reference_of(conf, root)
     mix = traffic.load_mix(root, cell["traffic"])
     if rate is not None:
         mix = dict(mix, rate_per_s=rate)
@@ -249,13 +262,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     m = conf["model"]
     m_ref = served_model(conf)
     kept = traffic.visual_kept(mix)
-    cfg = program_config(conf)
+    cfg = program_config(conf, ref)
     model = build(cfg)
-    params = weights.make(m, cfg.dtype, seed32(seed))
+    params = weights.make(ref.layout(m), cfg.dtype, seed32(seed))
     if not weights.same_layout(params, jax.eval_shape(
             model.init, jax.random.PRNGKey(0))):
         raise ValueError("the program's parameter layout is not the "
-                         "reference's (weights.layout)")
+                         "reference module's (layout)")
     jax.block_until_ready(params)
     marks["weights"] = time.perf_counter()
     lvlm = LVLM(model, params)
@@ -384,7 +397,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     # per-layer context, before the program's state goes
     ctx = SimpleNamespace(
         records=records, t0=t0, t_close=t_close, seconds=seconds,
-        model=m, mix=mix, visual_kept=kept, peaks=pk, flops=flops,
+        model=m, mix=mix, visual_kept=kept, peaks=pk, flops=ref,
         stats=stats, trace_reduce=trace_reduce,
         start=snaps["start"], end=snaps["end"], trace=None,
         probes=probes, modules=names)
@@ -428,7 +441,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     kw = dict(visual_kept=kept, pad_len=int(conf["engine"]["cache_len"]),
               max_served=int(mix["output"]["max"]))
     t_ref = clock()
-    gap = correctness.widest(correctness.gaps(m_ref, params, picked, **kw))
+    gap = correctness.widest(correctness.gaps(ref, m_ref, params, picked,
+                                              **kw))
     print(f"reference: {len(picked)} requests, "
           f"{sum(len(s.served) for s in picked)} served tokens, "
           f"{clock() - t_ref} s", file=sys.stderr, flush=True)
@@ -446,7 +460,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         # the control's first choices in place of the served tokens,
         # judged by the same verdict
         ctl = correctness.widest(correctness.gaps(
-            m_ref, params, picked, quant="fp8", **kw))
+            ref, m_ref, params, picked, quant="fp8", **kw))
         ok, ctl_checks = correctness.verdict(ctl, unfinished, len(picked),
                                              limits)
         out["control"] = {"correct": ok, "checks": ctl_checks}

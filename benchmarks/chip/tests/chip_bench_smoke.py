@@ -2,8 +2,9 @@
 
 ``make_tree(tmp)`` writes ``BENCHMARK.json`` and a benchmark directory
 with one smoke-sized configuration, mixes and limits under ``tmp``, and
-copies the real metric readers, so ``harness.run_cell(..., repo=tmp,
-root=tmp / "bench", require_chip=False)`` runs a whole cell in seconds.
+copies the real metric readers and reference modules, so
+``harness.run_cell(..., repo=tmp, root=tmp / "bench",
+require_chip=False)`` runs a whole cell in seconds.
 """
 from __future__ import annotations
 
@@ -82,6 +83,8 @@ def make_tree(tmp: Path) -> Path:
     for sub in ("configs", "traffic", "limits"):
         (root / sub).mkdir(parents=True)
     shutil.copytree(CHIP / "metrics", root / "metrics")
+    shutil.copytree(CHIP / "references", root / "references",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     bench["configs"] = []
     for name, conf in SMOKE_MODELS.items():
         (root / "configs" / f"{name}.json").write_text(json.dumps(conf))

@@ -84,7 +84,7 @@ def test_a_cell_added_as_files_runs_through_the_loaders(tmp_path):
     conf = harness.config_of(bench, tmp_path, cell["config"])
     assert conf["arch"] == "phi4-mini-3.8b"
     assert traffic.load_mix(root, cell["traffic"])["compression"] == "none"
-    cfg = harness.program_config(conf)
+    cfg = harness.program_config(conf, harness.reference_of(conf, root))
     assert (cfg.num_layers, cfg.d_model, cfg.vocab_size) == (2, 128, 512)
 
 

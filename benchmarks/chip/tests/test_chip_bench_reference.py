@@ -14,8 +14,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chip_bench_smoke import SMOKE_MODELS
+from chip_bench_smoke import CHIP, SMOKE_MODELS
 from benchmarks.chip import harness, reference, weights
+
+DENSE = harness.load_reference(CHIP, "dense_gqa")
 
 
 def _engine_logits(model, params, tokens, visual, served, cache_len):
@@ -43,8 +45,8 @@ def test_engine_prefill_and_cached_decode_match_the_reference(name):
     conf = SMOKE_MODELS[name]
     m = harness.served_model(conf)
     from repro.models.registry import build
-    model = build(harness.program_config(conf))
-    params = weights.make(m, "float32", 7)
+    model = build(harness.program_config(conf, DENSE))
+    params = weights.make(DENSE.layout(m), "float32", 7)
     assert weights.same_layout(params, jax.eval_shape(
         model.init, jax.random.PRNGKey(0)))
     rng = np.random.default_rng(3)
@@ -57,10 +59,10 @@ def test_engine_prefill_and_cached_decode_match_the_reference(name):
     with jax.default_matmul_precision("highest"):
         got = _engine_logits(model, params, tokens, visual, served,
                              cache_len)
-    ref = np.asarray(reference.served_logits(
+    ref = np.asarray(DENSE.served_logits(
         m, params, tokens, visual, served, cache_len, 8))[: len(served)]
     assert np.abs(got - ref).max() < 1e-4 * max(1.0, np.abs(ref).max())
-    ctl = np.asarray(reference.served_logits(
+    ctl = np.asarray(DENSE.served_logits(
         m, params, tokens, visual, served, cache_len, 8,
         quant="fp8"))[: len(served)]
     assert np.abs(ctl - ref).max() > 100 * np.abs(got - ref).max()
@@ -80,19 +82,19 @@ def test_the_reference_takes_the_served_values_over_the_published():
     assert conf["model"]["rms_norm_eps"] == 1e-05
     assert (m["rms_norm_eps"], m["partial_rotary_factor"],
             m["rope_scaling"]) == (1e-06, 1.0, None)
-    params = weights.make(conf["model"], "float32", 7)
+    params = weights.make(DENSE.layout(conf["model"]), "float32", 7)
     # the published LongRoPE is not in the reference: refused, not ignored
     with pytest.raises(ValueError, match="longrope"):
-        reference.served_logits(conf["model"], params, [1, 2, 3], None,
-                                [4, 5], 16, 4)
+        DENSE.served_logits(conf["model"], params, [1, 2, 3], None,
+                            [4, 5], 16, 4)
 
 
 def test_partial_rotary_leaves_the_rest_of_the_head_alone():
     x = jnp.asarray(np.random.default_rng(0).standard_normal((5, 2, 16)),
                     jnp.float32)
     pos = jnp.arange(5)
-    part = np.asarray(reference._rope(x, pos, 10000.0, 0.75))
-    whole = np.asarray(reference._rope(x, pos, 10000.0, 1.0))
+    part = np.asarray(reference.rope(x, pos, 10000.0, 0.75))
+    whole = np.asarray(reference.rope(x, pos, 10000.0, 1.0))
     assert np.array_equal(part[..., 12:], np.asarray(x)[..., 12:])
     assert not np.allclose(part[1:, :, :12], np.asarray(x)[1:, :, :12])
     assert not np.allclose(part, whole)
