@@ -1,10 +1,11 @@
 """Model: decode operations the delivered tokens needed, over the
 window and the chip's bf16 peak (%).
 
-Counts useful work only (``flops.window_ops``): a first token its
-prompt's prefill with the last position unembedded, a later token one
-decode row at its context. Whatever program computes it, this share
-bounds the rooflines of the kernels on the path."""
+Counts useful work only (``window_ops`` of the configuration's
+reference module, ``ctx.flops``): a first token its prompt's prefill with
+the last position unembedded, a later token one decode row at its
+context. Whatever program computes it, this share bounds the rooflines
+of the kernels on the path."""
 LAYER = "model"
 UNIT = "%"
 SOURCE = "host_clock"

@@ -1,5 +1,6 @@
-"""Reference modules: found by the name a configuration gives them, and
-the dense decoder's pinned to what the benchmark has read on it.
+"""Reference modules: found by the name a configuration gives them, the
+only files that know a family, and the dense decoder's pinned to what
+the benchmark has read on it.
 
 The literals pin what every reading of the dense cells rests on: the
 weights ``weights.make`` draws for seed 7 (a digest of each leaf's
@@ -11,10 +12,13 @@ change to any of them changes what the benchmark reads.
 from __future__ import annotations
 
 import hashlib
+import inspect
 import json
 import re
 import time
+from pathlib import Path
 from types import SimpleNamespace
+from typing import List
 
 import jax
 import numpy as np
@@ -94,9 +98,20 @@ COUNTS = {
 RECORDS = [(37, 0, [0.5, 1.1, 1.2, 1.9, 2.4]), (200, None, [1.0, 1.5]),
            (64, 1, [1.25, 1.75, 2.0]), (9, None, [2.5])]
 
-DENSE_NAMES = re.compile(
+# what a family publishes: the keys of dense, latent-attention and expert
+# models, and the paths of their weights; only references/ names them
+FAMILY_NAMES = re.compile(
     r"num_key_value_heads|num_attention_heads|intermediate_size|head_dim"
-    r"|layers/(attn|mlp|ln)")
+    r"|kv_lora_rank|q_lora_rank|qk_nope_head_dim|qk_rope_head_dim"
+    r"|v_head_dim|n_routed_experts|n_shared_experts|num_experts_per_tok"
+    r"|moe_intermediate_size|routed_scaling_factor|first_k_dense_replace"
+    r"|scoring_func|topk_method|(dense_)?layers/|projector/")
+
+# what the harness and the readers take from a reference module
+INTERFACE = ("program_fields", "layout", "served_logits", "prefill_cost",
+             "decode_cost", "window_ops", "least_time")
+
+MODULES = sorted(p.stem for p in (CHIP / "references").glob("*.py"))
 
 
 def _digest(x) -> str:
@@ -105,6 +120,27 @@ def _digest(x) -> str:
 
 def _published(name):
     return json.loads((CHIP / "configs" / f"{name}.json").read_text())
+
+
+def family_named(root: Path) -> List[str]:
+    """The files under ``root``, outside ``references/`` and ``tests/``,
+    that name a family's published keys or weight paths."""
+    named = []
+    for p in sorted(root.rglob("*.py")):
+        rel = p.relative_to(root)
+        if (rel.parts[0] not in ("references", "tests")
+                and FAMILY_NAMES.search(p.read_text())):
+            named.append(rel.as_posix())
+    return named
+
+
+def lacks(mod) -> List[str]:
+    """What ``mod`` does not give of a reference module's interface."""
+    missing = [f for f in INTERFACE if not callable(getattr(mod, f, None))]
+    if "served_logits" not in missing and "quant" not in \
+            inspect.signature(mod.served_logits).parameters:
+        missing.append("served_logits(quant=)")
+    return missing
 
 
 @pytest.mark.parametrize("name", sorted(WEIGHTS))
@@ -156,11 +192,57 @@ def test_a_configuration_without_a_reference_key_takes_dense_gqa(name):
     assert harness.reference_of(conf) is DENSE
 
 
-def test_only_the_dense_module_names_dense_keys():
-    named = [str(p.relative_to(CHIP)) for p in sorted(CHIP.rglob("*.py"))
-             if "tests" not in p.relative_to(CHIP).parts
-             and DENSE_NAMES.search(p.read_text())]
-    assert named == ["references/dense_gqa.py"]
+def test_no_file_outside_references_names_a_family_key():
+    assert family_named(CHIP) == []
+    # the pattern sees what a module names: the guard is not vacuous
+    assert FAMILY_NAMES.search(
+        (CHIP / "references" / "dense_gqa.py").read_text())
+
+
+@pytest.mark.parametrize("name", MODULES)
+def test_a_reference_module_gives_the_interface(name):
+    assert lacks(harness.load_reference(CHIP, name)) == []
+
+
+SECOND = '''"""dense_gqa's equations, beside the keys and weight paths of a
+latent-attention expert family, named as that family publishes them."""
+from pathlib import Path
+
+from benchmarks.chip import harness
+
+dense = harness.load_reference(Path(__file__).parents[1], "dense_gqa")
+least_time = dense.least_time
+PUBLISHED = ("num_attention_heads", "num_key_value_heads", "head_dim",
+             "intermediate_size", "kv_lora_rank", "q_lora_rank",
+             "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+             "n_routed_experts", "n_shared_experts", "num_experts_per_tok",
+             "moe_intermediate_size", "routed_scaling_factor",
+             "first_k_dense_replace", "scoring_func", "topk_method")
+LEAVES = ("layers/attn/wkv_a", "layers/attn/wkv_b", "layers/moe/router",
+          "dense_layers/mlp/wo", "projector/w1")
+program_fields, layout = dense.program_fields, dense.layout
+served_logits = dense.served_logits
+prefill_cost, decode_cost = dense.prefill_cost, dense.decode_cost
+window_ops = dense.window_ops
+'''
+
+PLANTED = ("num_attention_heads", "qk_rope_head_dim", "n_routed_experts",
+           "layers/attn/wkv_a", "dense_layers/mlp/wo", "projector/w1")
+
+
+def test_a_second_family_module_passes_the_guard(tmp_path):
+    root = make_tree(tmp_path)
+    (root / "references" / "second.py").write_text(SECOND)
+    assert all(name in SECOND for name in PLANTED)
+    assert family_named(root) == []
+    assert lacks(harness.load_reference(root, "second")) == []
+
+
+@pytest.mark.parametrize("name", PLANTED)
+def test_a_family_name_outside_references_is_caught(tmp_path, name):
+    root = make_tree(tmp_path)
+    (root / "metrics" / "x.py").write_text(f"KEY = {name!r}\n")
+    assert family_named(root) == ["metrics/x.py"]
 
 
 TOY = '''"""dense_gqa's equations, for a model that publishes its key-value
@@ -237,6 +319,8 @@ def _run(tmp_path, root):
 
 def test_a_reference_module_added_as_a_file_runs_a_cell(tmp_path):
     root = _add_toy_cell(tmp_path, "toy")
+    # the module names the keys it maps, as published and as renamed
+    assert family_named(root) == []
     out = _run(tmp_path, root)
     assert out["correct"], out["checks"]
     assert out["failed"] == 0 and out["attempted"] == 8
