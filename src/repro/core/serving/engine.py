@@ -60,6 +60,7 @@ from repro.core.serving.request import Request, State, summarize
 from repro.core.serving.scheduler import SCHEDULERS
 from repro.core.token_compression.policy import (CompressionStrategy,
                                                  LIVE_KV_SELECTORS)
+from repro.kernels.decode_attention import block_len, blocks_read
 
 
 @dataclasses.dataclass
@@ -134,15 +135,17 @@ class SamplingEngineDecoder:
         prof = eng.profiler
         if prof.enabled:
             prof.site_begin("decode_inputs")
-        toks = np.zeros((ec.max_batch, 1), np.int32)
+        # per row: the token fed in, and the positions it attends
+        toks = np.zeros((ec.max_batch, 2), np.int32)
         # fixed-shape decode runs EVERY slot; inactive slots (empty or
         # mid-prefill) must not corrupt real cache entries, so their write
         # lands on the reserved scratch position cache_len-1 (requests are
-        # capacity-checked to never reach it).
+        # capacity-checked to never reach it), and they attend nothing.
         pos = np.full(ec.max_batch, ec.cache_len - 1, np.int32)
         for r in reqs:
-            toks[r._slot, 0] = eng.slot_last_tok[r._slot]
-            pos[r._slot] = eng.slot_pos[r._slot]
+            s = r._slot
+            toks[s] = eng.slot_last_tok[s], eng.slot_pos[s] + 1
+            pos[s] = eng.slot_pos[s]
         toks, pos = jnp.asarray(toks), jnp.asarray(pos)
         if prof.enabled:
             prof.site_end("decode_inputs")
@@ -226,6 +229,15 @@ class Engine:
 
         self.pool = model.init_cache(ec.max_batch, ec.cache_len,
                                      windowed=self.windowed)
+        # positions per block of the decode's attention kernel, on a
+        # stacked full-length K/V pool (``decode_kv_blocks``); else None
+        k_pool = self.pool.get("layers", {}).get("k")
+        self._decode_block = None
+        if (k_pool is not None and not self.windowed
+                and cfg.family in ("dense", "vlm", "moe")):
+            _, _, s_len, kvh, hd = k_pool.shape
+            self._decode_block = block_len(s_len, kvh, hd,
+                                           k_pool.dtype.itemsize)
         self.slot_req: List[Optional[Request]] = [None] * ec.max_batch
         self.slot_pos = np.zeros(ec.max_batch, np.int64)   # next write pos
         self.slot_last_tok = np.zeros(ec.max_batch, np.int64)
@@ -287,9 +299,12 @@ class Engine:
         def engine_extend(p, cache, tokens, start):
             return self.model.extend(p, cache, tokens, start)
 
+        # ``toks`` [B, 2]: each row's input token and attended length (one
+        # host-to-device copy; the program keeps four arguments)
         def engine_decode(p, pool, toks, pos):
-            return self.model.decode_step(p, pool, toks, pos,
-                                          windowed=self.windowed)
+            return self.model.decode_step(p, pool, toks[:, :1], pos,
+                                          windowed=self.windowed,
+                                          lens=toks[:, 1])
 
         self._jit_prefill = jax.jit(engine_prefill)
         self._jit_extend = jax.jit(engine_extend)
@@ -1100,6 +1115,11 @@ class Engine:
             if self.profiler.enabled:
                 self.profiler.site_begin(f"decode:{name}", rows=len(group))
                 self.profiler.count("decode_rows", len(group))
+                if self._decode_block:
+                    # KV blocks one layer's attention copies: lens, not pool
+                    self.profiler.count("decode_kv_blocks", blocks_read(
+                        [self.slot_pos[r._slot] + 1 for r in group],
+                        self._decode_block))
             emitted_all.update(dec.engine_decode(self, group))
             if self._iter_decode_cost is None:
                 ctx = float(np.mean([self.slot_pos[r._slot] for r in group]))

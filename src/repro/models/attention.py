@@ -6,8 +6,9 @@ Three execution paths per layer:
                               bidirectional; optional sliding window.
   * ``prefill_into_cache`` -- prefill that also materializes the KV cache.
   * ``decode_attention``   -- one token vs a cache (full or ring-buffer
-                              window). Dense serve_step uses this; the paged
-                              engine uses kernels/paged_attention instead.
+                              window). On the engine's stacked pool on a
+                              TPU it runs kernels/decode_attention, which
+                              reads only each row's live positions.
 
 GQA is computed grouped (q reshaped [B,S,K,G,D]) so KV heads are never
 materialized repeated -- this matters for both HLO bytes and the roofline.
@@ -20,6 +21,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.decode_attention import pool_decode_attention
+from repro.kernels.ops import interpret_mode
 from repro.models.layers import ParamSpec, spec, apply_rope
 
 NEG_INF = -1e30
@@ -413,12 +416,21 @@ def mla_append_attention(p, x, cos, sin, cfg, cache, start, *, window=0):
     return out, cache
 
 
+def _pool_kernel_backend() -> bool:
+    """The stacked-pool decode runs the Pallas kernel on a TPU only;
+    elsewhere its interpreter would be far slower than XLA."""
+    return jax.default_backend() == "tpu"
+
+
 def decode_attention(p, x, cos, sin, cfg, cache, pos, *, window=0,
-                     layer=None):
+                     layer=None, lens=None):
     """One-token decode vs cache. x [B,1,d]; pos [B] per-request int32.
 
     ``cache`` is one layer's, or with ``layer`` the stacked cache; the new
-    token is written first (``write_decode_rows``), then attended."""
+    token is written first (``write_decode_rows``), then attended. On a
+    stacked full-length cache on a TPU, ``pool_decode_attention`` attends
+    the first ``lens [B]`` positions of each row (default ``pos + 1``; 0
+    for a row whose output is ignored) and reads no other KV."""
     b = x.shape[0]
     pos = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(pos, jnp.int32)), (b,))
     q, k, v = qkv_proj(p, x)
@@ -426,6 +438,13 @@ def decode_attention(p, x, cos, sin, cfg, cache, pos, *, window=0,
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
     cache = write_decode_rows(cache, {"k": k[:, 0], "v": v[:, 0]}, pos, layer)
+    if (layer is not None and "slot_pos" not in cache and not window
+            and _pool_kernel_backend()):
+        o = pool_decode_attention(
+            q[:, 0], cache["k"], cache["v"], layer,
+            pos + 1 if lens is None else lens,
+            interpret=interpret_mode())
+        return out_proj(p, o[:, None]), cache
     lc = _layer_of(cache, layer)
     k_pos = (lc["slot_pos"] if "slot_pos" in lc
              else jnp.arange(lc["k"].shape[1], dtype=jnp.int32))

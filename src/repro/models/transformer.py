@@ -130,9 +130,11 @@ def _dense_layer_prefill(cfg, p, x, cos, sin, cache, *, positions, window,
 
 
 def _dense_layer_decode(cfg, p, x, cos, sin, cache, pos, *, window,
-                        moe_cap=None, weight_stationary=False, layer=None):
+                        moe_cap=None, weight_stationary=False, layer=None,
+                        lens=None):
     """One layer's decode. ``cache`` is that layer's, or with ``layer`` the
-    stacked cache, whose layer ``layer`` gets the new token in place."""
+    stacked cache, whose layer ``layer`` gets the new token in place.
+    ``lens``: positions each row attends (``decode_attention``)."""
     if weight_stationary:
         x = L.constrain_replicated(x)
     h = L.apply_norm(p["ln1"], x, cfg.norm)
@@ -142,7 +144,8 @@ def _dense_layer_decode(cfg, p, x, cos, sin, cache, pos, *, window,
                                              layer=layer)
     else:
         a, cache = attn.decode_attention(p["attn"], h, cos, sin, cfg, cache,
-                                         pos, window=window, layer=layer)
+                                         pos, window=window, layer=layer,
+                                         lens=lens)
     x = x + a
     h = L.apply_norm(p["ln2"], x, cfg.norm)
     if cfg.num_experts and "moe" in p:
@@ -734,11 +737,14 @@ class Model:
     def decode_step(self, params, cache, tokens, pos, *,
                     windowed: bool = False, window: Optional[int] = None,
                     moe_cap: Optional[float] = None,
-                    weight_stationary: bool = False):
+                    weight_stationary: bool = False, lens=None):
         """tokens [B,1] -> (logits [B,V], new cache).
 
         pos: scalar int32 (all requests at the same position -- dry-run)
         or [B] per-request positions (continuous batching).
+        lens: [B] positions each row attends, ``pos + 1`` by default; 0
+        marks a row whose logits are ignored, whose KV the stacked-pool
+        attention kernel then does not read.
         """
         cfg = self.cfg
         window = (cfg.sliding_window if windowed else 0) if window is None \
@@ -760,7 +766,7 @@ class Model:
                     lp, i = xs
                     return _dense_layer_decode(
                         lcfg, lp, x, cos, sin, stack, pos, window=window,
-                        layer=i, **kw), None
+                        layer=i, lens=lens, **kw), None
                 n = jax.tree.leaves(stack)[0].shape[0]
                 (x, stack), _ = jax.lax.scan(
                     body, (x, stack),

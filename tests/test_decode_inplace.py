@@ -137,3 +137,42 @@ def test_engine_decode_donates_pool():
     by_rid = {r.rid: r.generated for r in served.requests}
     assert [r.generated for r in reqs] == [by_rid[r.rid] for r in reqs]
     assert all(len(r.generated) == 8 for r in reqs)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-vl-2b", "phi4-mini-3.8b"])
+def test_pool_kernel_decode_step_matches_xla_path(arch, monkeypatch):
+    """The stacked-pool decode through the Pallas kernel (interpreted off
+    a TPU) gives today's logits for every live row, finite logits for an
+    inactive slot, and the same pool, which it still takes by donation."""
+    cfg = get_config(arch, smoke=True)
+    model = build(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    cache = _filled_cache(model, False, jax.random.PRNGKey(1))
+    toks = jax.random.randint(jax.random.PRNGKey(2), (B, 1), 1,
+                              cfg.vocab_size)
+    # two live rows, one inactive slot on the scratch position
+    pos = jnp.asarray([5, 70, CACHE_LEN - 1], jnp.int32)
+    lens = jnp.asarray([6, 71, 0], jnp.int32)
+    xla_logits, xla_cache = jax.jit(lambda p, c, t, q: model.decode_step(
+        p, c, t, q))(params, cache, toks, pos)
+    monkeypatch.setattr(attn, "_pool_kernel_backend", lambda: True)
+    step = jax.jit(lambda p, c, t, q, n: model.decode_step(p, c, t, q,
+                                                           lens=n),
+                   donate_argnums=(1,))
+    held = jax.tree.map(jnp.copy, cache)
+    logits, out = step(params, held, toks, pos, lens)
+    assert all(a.is_deleted() for a in jax.tree.leaves(held))
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda c: model.decode_step(params, c, toks, pos, lens=lens))(cache))
+    logits, xla_logits = np.asarray(logits), np.asarray(xla_logits)
+    assert np.isfinite(logits).all()
+    np.testing.assert_allclose(logits[:2], xla_logits[:2], atol=1e-5,
+                               rtol=1e-5)
+
+    def same_pool(a, r):
+        # the inactive slot's scratch entry holds whatever its ignored
+        # row computed; every other entry is today's
+        a, r = np.array(a), np.array(r)
+        a[:, 2, CACHE_LEN - 1] = r[:, 2, CACHE_LEN - 1] = 0
+        np.testing.assert_allclose(a, r, atol=1e-5, rtol=1e-5)
+    jax.tree.map(same_pool, out, xla_cache)

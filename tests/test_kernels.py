@@ -133,3 +133,46 @@ def test_flash_matches_model_attention():
     np.testing.assert_allclose(
         np.asarray(jnp.moveaxis(o_kernel, 2, 1).reshape(b, s, kvh * g, d)),
         np.asarray(o_model), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("kvh,g", [(2, 6), (8, 3)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pool_decode_attention_vs_sdpa(kvh, g, dtype, rng):
+    """One layer of a stacked [L,B,S,KVH,D] pool, each row attending its
+    first ``lens`` positions, as ``simple_sdpa`` masks causally at
+    ``pos = lens - 1``. Rows end before, on and after a block's edge and
+    at S-1; past each length the pool holds large stale values, which must
+    not reach the output; a row of length 0 gives a finite output."""
+    from repro.kernels.decode_attention import pool_decode_attention
+    from repro.models.attention import simple_sdpa
+    n_layers, s_len, d, bk, layer = 3, 64, 32, 16, 1
+    lens = np.array([0, 1, bk, bk + 1, s_len - 1], np.int32)
+    b, h = len(lens), kvh * g
+    k = rng.randn(n_layers, b, s_len, kvh, d)
+    v = rng.randn(n_layers, b, s_len, kvh, d)
+    stale = np.arange(s_len)[None, None, :, None, None] \
+        >= lens[None, :, None, None, None]
+    k, v = np.where(stale, 50.0, k), np.where(stale, 1e3, v)
+    k, v = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
+    q = jnp.asarray(rng.randn(b, h, d), dtype)
+    out = pool_decode_attention(q, k, v, jnp.int32(layer), jnp.asarray(lens),
+                                block=bk, interpret=True)
+    pos = np.maximum(lens - 1, 0)
+    expected = simple_sdpa(q.reshape(b, 1, kvh, g, d), k[layer], v[layer],
+                           q_pos=jnp.asarray(pos)[:, None],
+                           k_pos=jnp.arange(s_len), causal=True)[:, 0]
+    out = np.asarray(out, np.float32)
+    assert out.shape == (b, h, d) and np.isfinite(out).all()
+    np.testing.assert_allclose(out[1:], np.asarray(expected, np.float32)[1:],
+                               atol=TOL[dtype], rtol=TOL[dtype])
+
+
+def test_pool_decode_block_len_moves_a_fixed_byte_count():
+    """The block follows KVH * D: 256 KiB of K a copy at both cells'
+    widths, and a divisor of the cache with whole sublane tiles."""
+    from repro.kernels.decode_attention import block_len, blocks_read
+    assert block_len(2048, 2, 128, 2) == 512            # qwen2-vl-2b
+    assert block_len(2048, 8, 128, 2) == 128            # phi4-mini
+    assert block_len(96, 2, 16, 4) == 96
+    assert block_len(100, 2, 128, 2) == 100             # no tiled divisor
+    assert blocks_read([0, 1, 512, 513, 2047], 512) == 0 + 1 + 1 + 2 + 4

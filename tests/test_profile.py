@@ -579,3 +579,30 @@ def test_time_jit_reports_min_mean_std():
     assert set(stats) == {"min", "mean", "std"}
     # the float value formats like the old scalar return (emit() rows)
     assert f"{t:.1f}" == f"{t.min_us:.1f}"
+
+
+def test_decode_kv_blocks_counts_live_blocks(lvlm):
+    """``decode_kv_blocks`` counts the KV blocks one layer's attention
+    kernel copies in a decode launch: each live row's positions in whole
+    blocks, and nothing for the empty slots of the pool."""
+    from repro.core.serving import Engine, State
+    from repro.kernels.decode_attention import block_len
+    cfg = lvlm.cfg
+    ec = _ec(max_batch=4, cache_len=2048)
+    prof = Profiler()
+    eng = Engine(lvlm.model, lvlm.params, ec, profiler=prof)
+    bk = block_len(ec.cache_len, cfg.num_kv_heads, cfg.head_dim, 4)
+    assert eng._decode_block == bk == 1024
+    reqs = _reqs([[7] * 9, [7] * (bk + 76)], new=8)
+    for r in reqs:
+        eng.submit(r)
+    while eng.waiting or any(r.state != State.DECODE for r in reqs):
+        assert eng.step()
+    before = prof.snapshot().get("decode_kv_blocks",
+                                 {"count": 0, "total": 0})
+    pos = sorted(int(eng.slot_pos[r._slot]) for r in reqs)
+    assert pos == [9, bk + 76]          # both prefilled, no decode yet
+    assert eng.step()                   # one decode launch of two rows
+    after = prof.snapshot()["decode_kv_blocks"]
+    assert after["count"] == before["count"] + 1
+    assert after["total"] == before["total"] + 1 + 2

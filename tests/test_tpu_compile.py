@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core.serving import Engine, EngineConfig
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.decode_attention import pool_decode_attention
 from repro.kernels.paged_attention import paged_attention
 from repro.models.registry import build
 
@@ -95,12 +96,18 @@ def test_engine_prefill_compiles(engine, on_chip):
     _fits(compiled)
 
 
+def _decode_args(engine, on_chip):
+    """The decode program's arguments: params, pool, each row's token and
+    attended length [B, 2], positions [B]."""
+    return (on_chip(engine.params), on_chip(engine.pool),
+            on_chip(jax.ShapeDtypeStruct((MAX_BATCH, 2), jnp.int32)),
+            on_chip(jax.ShapeDtypeStruct((MAX_BATCH,), jnp.int32)))
+
+
 def test_engine_decode_step_compiles(engine, on_chip):
     """One fixed-shape decode step over the [4, 2048] slot pool."""
     compiled = engine._jit_decode.lower(
-        on_chip(engine.params), on_chip(engine.pool),
-        on_chip(jax.ShapeDtypeStruct((MAX_BATCH, 1), jnp.int32)),
-        on_chip(jax.ShapeDtypeStruct((MAX_BATCH,), jnp.int32))).compile()
+        *_decode_args(engine, on_chip)).compile()
     _fits(compiled)
 
 
@@ -131,14 +138,42 @@ def test_engine_decode_writes_pool_in_place(engine, on_chip):
     new rows: XLA aliases the whole pool to the output, and no op writes a
     layer of it back whole."""
     compiled = engine._jit_decode.lower(
-        on_chip(engine.params), on_chip(engine.pool),
-        on_chip(jax.ShapeDtypeStruct((MAX_BATCH, 1), jnp.int32)),
-        on_chip(jax.ShapeDtypeStruct((MAX_BATCH,), jnp.int32))).compile()
+        *_decode_args(engine, on_chip)).compile()
     pool = jax.tree.leaves(engine.pool)
     assert compiled.memory_analysis().alias_size_in_bytes >= sum(
         a.nbytes for a in pool)
     hlo = compiled.as_text()
     for shape in {a.shape for a in pool}:
+        assert _whole_layer_writes(hlo, shape) == [], shape
+
+
+def _layer_sized(hlo: str, stack_shape) -> list:
+    """Instructions of an optimized HLO module whose result is one whole
+    layer of a stacked cache leaf, such as a layer sliced out to read."""
+    layer = ",".join(str(d) for d in stack_shape[1:])
+    return re.findall(rf"^\s*(?:ROOT )?%(\S+) = \w+\[{layer}\]", hlo, re.M)
+
+
+def test_engine_decode_reads_only_live_kv(on_chip, monkeypatch):
+    """On a TPU the decode attends through ``pool_decode_attention``: the
+    program holds the kernel, still takes the pool by donation, and no op
+    slices a whole layer of K or V out of the pool."""
+    from repro.models import attention
+    monkeypatch.setattr(attention, "_pool_kernel_backend", lambda: True)
+    monkeypatch.setattr(attention, "interpret_mode", lambda: False)
+    model = build(get_config("qwen2-vl-2b"))
+    engine = Engine(model, model.abstract_params(),
+                    EngineConfig(max_batch=MAX_BATCH, cache_len=CACHE_LEN))
+    compiled = engine._jit_decode.lower(
+        *_decode_args(engine, on_chip)).compile()
+    _fits(compiled)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    pool = jax.tree.leaves(engine.pool)
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.nbytes for a in pool)
+    for shape in {a.shape for a in pool}:
+        assert _layer_sized(hlo, shape) == [], shape
         assert _whole_layer_writes(hlo, shape) == [], shape
 
 
@@ -156,11 +191,19 @@ def _kernel_cases():
               ((kvh, MAX_BATCH * pages, PAGE, d), jnp.bfloat16),
               ((MAX_BATCH, pages), jnp.int32),
               ((MAX_BATCH,), jnp.int32)])
-    return {"flash_attention": flash, "paged_attention": paged}
+    pool = (partial(pool_decode_attention, interpret=False),
+            [((MAX_BATCH, h, d), jnp.bfloat16),
+             ((cfg.num_layers, MAX_BATCH, CACHE_LEN, kvh, d), jnp.bfloat16),
+             ((cfg.num_layers, MAX_BATCH, CACHE_LEN, kvh, d), jnp.bfloat16),
+             ((), jnp.int32),
+             ((MAX_BATCH,), jnp.int32)])
+    return {"flash_attention": flash, "paged_attention": paged,
+            "pool_decode_attention": pool}
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
-@pytest.mark.parametrize("kernel", ["flash_attention", "paged_attention"])
+@pytest.mark.parametrize("kernel", ["flash_attention", "paged_attention",
+                                    "pool_decode_attention"])
 def test_pallas_kernel_compiles_natively(kernel, precision, on_chip):
     """Both matmul precisions: ``highest`` is what the kernel tests use."""
     fn, shapes = _kernel_cases()[kernel]
